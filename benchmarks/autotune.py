@@ -49,10 +49,12 @@ def _sweep() -> dict[str, list[tuple]]:
                  ((n(1, (8, 4096)),), {})],
         "matmul": [((n(2, (512, 512)), n(3, (512, 512))), {}),
                    ((n(4, (256, 256)), n(5, (256, 256))), {}),
-                   # above the modeled Strassen crossover: the search covers
-                   # backend/cutoff/morton variants alongside the tile ladder
-                   # (bench_kernels' matmul_strassen shape)
-                   ((n(19, (1024, 1024)), n(20, (1024, 1024))), {})],
+                   # above the modeled Strassen crossover, Strassen requested:
+                   # the search covers backend/cutoff/morton variants
+                   # alongside the tile ladder (bench_kernels'
+                   # matmul_strassen shape)
+                   ((n(19, (1024, 1024)), n(20, (1024, 1024))),
+                    {"backend": "strassen"})],
         "transpose": [((n(6, (512, 512)),), {}),
                       ((n(7, (256, 256)),), {})],
         "attention": [((n(8, (8, 512, 64)), n(9, (8, 512, 64)),
@@ -80,6 +82,8 @@ def main(argv=None) -> dict:
     ap.add_argument("--dir", default=None,
                     help="table directory (else REPRO_TUNE_DIR / default)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
 
     if args.dir:
         os.environ["REPRO_TUNE_DIR"] = args.dir

@@ -88,9 +88,9 @@ def _cases():
     kv_len = 1000
     xc = (jax.random.normal(key(6), (4, 1024))
           + 1j * jax.random.normal(key(7), (4, 1024))).astype(jnp.complex64)
-    # the largest benched square shape: above the modeled crossover, so the
-    # planner routes the Strassen backend; the classical extra arm measures
-    # the same shape with the backend forced back
+    # the largest benched square shape, above the modeled crossover, with
+    # the Strassen schedule requested (the planner never picks it unasked);
+    # the classical extra arm measures the same shape without it
     a2 = jax.random.normal(key(11), (1024, 1024), jnp.float32)
     b2 = jax.random.normal(key(12), (1024, 1024), jnp.float32)
     return {
@@ -99,7 +99,8 @@ def _cases():
         "matmul": dict(op="matmul", args=(a, b), kwargs={}, label="512",
                        derived=lambda us: f"{2 * 512**3 / (us / 1e6) / 1e9:.1f}GFLOP/s"),
         "matmul_strassen": dict(
-            op="matmul", args=(a2, b2), kwargs={}, label="1024",
+            op="matmul", args=(a2, b2), kwargs={"backend": "strassen"},
+            label="1024",
             extra_arms={"pallas_classical": {"backend": "classical"}},
             derived=lambda us: f"{2 * 1024**3 / (us / 1e6) / 1e9:.1f}GFLOP/s"),
         "transpose": dict(op="transpose", args=(a,), kwargs={}, label="512",
@@ -510,6 +511,8 @@ def _bench_serve_router() -> dict:
 
 
 def main(json_path: str | None = None, ops: list[str] | None = None) -> dict:
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     results: dict[str, dict] = {}
     cases = _cases()
     if ops:
@@ -519,7 +522,10 @@ def main(json_path: str | None = None, ops: list[str] | None = None) -> dict:
         plan = dict(registry.get(op).plan(*args))
         entry: dict = {"op": op, "shape": case["label"], "planned_tiles": plan}
 
-        ref_fn = jax.jit(lambda *a, _n=op, _kw=kwargs: registry.dispatch(
+        # the oracle takes the semantic kwargs only (no schedule request)
+        semantic = {k: v for k, v in kwargs.items()
+                    if k not in registry.get(op).pallas_only}
+        ref_fn = jax.jit(lambda *a, _n=op, _kw=semantic: registry.dispatch(
             _n, *a, impl="ref", **_kw))
         us = timeit(ref_fn, *args)
         entry["ref_us"] = round(us, 1)
@@ -531,8 +537,8 @@ def main(json_path: str | None = None, ops: list[str] | None = None) -> dict:
         arms += list(case.get("extra_arms", {}).items())
         with autotune.mode_scope("off"):
             for arm, tiles in arms:
-                fn = jax.jit(lambda *a, _n=op, _kw=kwargs, _t=tiles: registry.dispatch(
-                    _n, *a, impl="pallas", **_kw, **_t))
+                fn = jax.jit(lambda *a, _n=op, _kw={**kwargs, **tiles}:
+                             registry.dispatch(_n, *a, impl="pallas", **_kw))
                 us = timeit(fn, *args, iters=5)
                 entry[f"{arm}_us"] = round(us, 1)
                 print(f"kernel_{name}_{arm}_{case['label']},{us:.0f},interpret")

@@ -1,4 +1,4 @@
-"""qwen3-1.7b — dense, GQA (kv=8), qk_norm.  [hf:Qwen/Qwen3-8B; hf]"""
+"""qwen3-1.7b — dense, GQA (kv=8), qk_norm.  [hf:Qwen/Qwen3-1.7B config.json]"""
 from repro.configs.base import ModelConfig, reduced, register
 
 CONFIG = ModelConfig(

@@ -93,24 +93,28 @@ points, each in exactly one module:
 
 Backend selection
 -----------------
-``matmul`` is a multi-backend op: ``planner.plan_matmul`` carries a
-``backend`` field chosen by comparing the costmodel envelopes
-(``seq_cache_complexity_strassen`` vs the classical Q) at the queried
-device params — "strassen" (the paper's Type-2 Depth-n-MM exemplar,
-W = n^2.807) for square, pow2-friendly, fp32/bf16 shapes above the modeled
-crossover edge (~sqrt M), "classical" otherwise — plus the recursion
-``cutoff`` beneath which ``strassen_matmul``'s 7-product quadrant schedule
-leaves dispatch to the Morton-ordered ``hbp_matmul`` tile kernel with f32
-accumulation preserved through the combination tree.  The registry's
-``matmul`` entry (``strassen_matmul.matmul``) resolves the variant at
-dispatch and registers a custom VJP (dA = g Bᵀ, dB = Aᵀ g, each
-re-planned for its own shape), so model matmuls (``models.common``'s
+``matmul`` is a multi-backend op: "classical" by default, "strassen" (the
+paper's Type-2 Depth-n-MM exemplar, W = n^2.807) only on request — the
+``backend="strassen"`` kwarg or the policy variant
+``matmul=pallas:backend=strassen`` — because its quadrant sums add row i
+to row i + m/2: where rows are tokens of different requests, one
+request's numbers would depend on what shares its launch.  A request is
+gated by ``planner.plan_matmul_backend``, which compares the costmodel
+envelopes (``seq_cache_complexity_strassen`` vs the classical Q) at the
+queried device params: square, pow2-friendly, fp32/bf16 shapes above the
+modeled crossover edge (~sqrt M) recurse down to a ``cutoff`` beneath which
+``strassen_matmul``'s 7-product quadrant schedule leaves dispatch to the
+Morton-ordered ``hbp_matmul`` tile kernel with f32 accumulation preserved
+through the combination tree.  The registry's ``matmul`` entry
+(``strassen_matmul.matmul``) runs the variant at dispatch and registers a
+custom VJP (dA = g Bᵀ, dB = Aᵀ g, each a classical product), so model
+matmuls (``models.common``'s
 ``project``/``gated_mlp``/``logits_matmul``/``expert_project`` — MLPs, QKV
 and output projections, logits, MoE expert slabs) route through the
 kernels under training and serving alike whenever the ambient policy says
-so.  A forced variant (policy ``variants`` or call-site kwarg) keys the
-autotune replay lookup, so a pinned-classical run never replays tiles
-tuned for the Strassen entry.
+so.  A requested variant (policy ``variants`` or call-site kwarg) keys the
+autotune replay lookup, so a classical run never replays tiles tuned for
+the Strassen entry.
 
 Tuning
 ------

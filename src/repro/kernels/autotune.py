@@ -215,12 +215,7 @@ def sem_class(op: str, args, kwargs: Optional[dict] = None) -> str:
         if args[1].dtype != args[0].dtype:
             parts.append(f"kv_dtype={jnp.dtype(args[1].dtype).name}")
     if op == "matmul":
-        backend = kwargs.get("backend")
-        if backend is None:
-            backend = planner.plan_matmul(
-                args[0].shape[0], args[0].shape[1], args[1].shape[1],
-                args[0].dtype).get("backend", "classical")
-        parts.append(f"backend={backend}")
+        parts.append(f"backend={kwargs.get('backend') or 'classical'}")
     return ",".join(parts)
 
 
@@ -337,7 +332,9 @@ def _scan_dims(x):
 
 
 def _scan_ws(plan, x):
-    return 4 * plan["block"] * jnp.dtype(x.dtype).itemsize
+    rows = x.shape[0] if x.ndim > 1 else 1
+    return planner.scan_working_set(plan["block"], jnp.dtype(x.dtype).itemsize,
+                                    rows)
 
 
 def _matmul_dims(a, b):
@@ -345,9 +342,8 @@ def _matmul_dims(a, b):
 
 
 def _matmul_ws(plan, a, b):
-    itemsize = jnp.dtype(a.dtype).itemsize
-    bm, bn, bk = plan["bm"], plan["bn"], plan["bk"]
-    return (bm * bk + bk * bn) * itemsize + 4 * bm * bn
+    return planner.matmul_working_set(plan["bm"], plan["bn"], plan["bk"],
+                                      jnp.dtype(a.dtype).itemsize)
 
 
 def _transpose_dims(x):
@@ -356,7 +352,8 @@ def _transpose_dims(x):
 
 
 def _transpose_ws(plan, x):
-    return 2 * plan["bt"] ** 2 * jnp.dtype(x.dtype).itemsize
+    return planner.transpose_working_set(plan["bt"],
+                                         jnp.dtype(x.dtype).itemsize)
 
 
 def _attention_dims(q, k, v):
@@ -364,36 +361,29 @@ def _attention_dims(q, k, v):
 
 
 def _attention_ws(plan, q, k, v):
-    itemsize = jnp.dtype(q.dtype).itemsize
-    kv_item = jnp.dtype(k.dtype).itemsize  # quantized KV: narrower panels
-    hd = q.shape[2]
-    qb, kb = plan["q_block"], plan["kv_block"]
-    # q rows + f32 acc rows, k/v panels (kv width), the f32 P tile,
-    # (m, l) columns
-    return qb * hd * (itemsize + 4) + 2 * kb * hd * kv_item \
-        + 4 * qb * kb + 8 * qb
+    # quantized KV: narrower k/v panels
+    return planner.attention_working_set(
+        plan["q_block"], plan["kv_block"], q.shape[2],
+        jnp.dtype(q.dtype).itemsize, jnp.dtype(k.dtype).itemsize)
 
 
 def _matmul_variants(base, a, b, dp=None):
-    """Backend/schedule alternatives around the analytic matmul choice: flip
-    the ``morton`` grid flag, walk the Strassen cutoff one octave each way,
-    and always offer the *other* backend when the shape admits it — the
-    measured crossover may sit on either side of the modeled one."""
+    """Schedule alternatives around the requested matmul: flip the
+    ``morton`` grid flag; under a Strassen request also walk its cutoff one
+    octave each way (one octave under the modeled gate when the planner
+    would not recurse: leaves of n/2) and offer the classical schedule.  A
+    classical request never tries Strassen: that schedule mixes rows, so
+    it changes what a row computes, and runs only where a caller asks."""
     base = dict(base)
     out = [base]
     n = b.shape[1]
-    square = a.shape[0] == a.shape[1] == n
     out.append({**base, "morton": False})
     if base.get("backend") == "strassen":
-        cut = int(base.get("cutoff", n))
+        cut = int(base.get("cutoff") or n)
         for c in (cut * 2, cut // 2):
             if 64 <= c < n and c != cut:
                 out.append({**base, "cutoff": c})
         out.append({"backend": "classical"})
-    elif (square and n % 2 == 0 and n // 2 >= 64
-          and jnp.dtype(a.dtype).name in planner._STRASSEN_DTYPES):
-        # one octave under the modeled gate: leaves of n/2
-        out.append({**base, "backend": "strassen", "cutoff": n // 2})
     return out
 
 
@@ -460,20 +450,23 @@ def snap_plan(op: str, args, plan: dict) -> dict:
 
 
 def candidates(op: str, *args, dp: Optional[planner.DeviceParams] = None,
-               max_candidates: int = 16, span: int = 2) -> list[dict]:
+               max_candidates: int = 16, span: int = 2,
+               variant: Optional[dict] = None) -> list[dict]:
     """Power-of-two ladder around the analytic plan: each tile kwarg ranges
     over factor 2**±``span`` of its planned value (snapped to divisors of its
     axis), the cross product is filtered by the fast-memory envelope, crossed
     with the op's variant alternatives (backend/cutoff, morton — see
     ``OpTuneInfo.variants``), and ranked by log-distance from the analytic
-    point (each variant hop counts one octave).  The analytic plan is always
+    point (each variant hop counts one octave).  ``variant`` holds the
+    call's requested variant knobs (e.g. ``backend="strassen"``): the
+    alternatives are taken around them.  The analytic plan is always
     candidate 0."""
     from repro.kernels import registry  # the layer below; lazy to stay acyclic
 
     spec = registry.get(op)
     info = _TUNE[op]
     dp = dp or planner.device_params()
-    analytic = dict(spec.plan(*args))
+    analytic = {**spec.plan(*args, dp=dp), **(variant or {})}
     dims = info.dims(*args)
     tile_analytic = {k: v for k, v in analytic.items() if k in dims}
     variant_analytic = {k: v for k, v in analytic.items() if k not in dims}
@@ -557,8 +550,10 @@ def search(op: str, *args, iters: int = 5, max_candidates: int = 16,
         raise TypeError(f"autotune.search({op!r}) needs concrete arrays, "
                         "not tracers")
     dp = planner.device_params()
-    interpret = not spec.supported()
-    cands = candidates(op, *args, dp=dp, max_candidates=max_candidates)
+    interpret = planner.resolve_interpret(None, spec.supported())
+    variant = {k: v for k, v in kwargs.items() if k in variant_keys(op)}
+    cands = candidates(op, *args, dp=dp, max_candidates=max_candidates,
+                       variant=variant)
     timed = []
     for plan in cands:
         try:

@@ -38,7 +38,7 @@ def _dft_factors(n: int) -> tuple[np.ndarray, np.ndarray]:
     return w.real.astype(np.float32), w.imag.astype(np.float32)
 
 
-def _cmatmul(ar, ai, br, bi, *, interpret: bool):
+def _cmatmul(ar, ai, br, bi, *, interpret: Optional[bool]):
     """(ar + i*ai) @ (br + i*bi) via four Morton-ordered Pallas matmuls."""
     rr = hbp_matmul(ar, br, interpret=interpret) - hbp_matmul(
         ai, bi, interpret=interpret)
@@ -49,7 +49,7 @@ def _cmatmul(ar, ai, br, bi, *, interpret: bool):
 
 @functools.partial(jax.jit, static_argnames=("n1", "interpret"))
 def bi_fft(x: jax.Array, *, n1: Optional[int] = None,
-           interpret: bool = True) -> jax.Array:
+           interpret: Optional[bool] = None) -> jax.Array:
     """DFT along the last axis.  x: (rows, n) real or complex, n a power of
     two.  Returns complex64 (rows, n)."""
     rows, n = x.shape

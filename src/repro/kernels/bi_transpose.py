@@ -27,12 +27,12 @@ def _transpose_kernel(x_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("bt", "morton", "interpret"))
 def bi_transpose(x: jax.Array, *, bt: Optional[int] = None, morton: bool = True,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: Optional[bool] = None) -> jax.Array:
     """x: (m, n) -> (n, m), tile-blocked."""
+    from repro.kernels import planner
+
     m, n = x.shape
     if bt is None:
-        from repro.kernels import planner
-
         bt = planner.plan_transpose(m, n, x.dtype)["bt"]
     bt_m, bt_n = min(bt, m), min(bt, n)
     assert m % bt_m == 0 and n % bt_n == 0
@@ -55,5 +55,5 @@ def bi_transpose(x: jax.Array, *, bt: Optional[int] = None, morton: bool = True,
         in_specs=[pl.BlockSpec((bt_m, bt_n), in_map)],
         out_specs=pl.BlockSpec((bt_n, bt_m), out_map),
         out_shape=jax.ShapeDtypeStruct((n, m), x.dtype),
-        interpret=interpret,
+        interpret=planner.resolve_interpret(interpret),
     )(x)

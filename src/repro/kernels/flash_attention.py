@@ -73,7 +73,13 @@ f32 copy never exists outside VMEM.  The quantized path is forward-only
 the queried device via ``repro.kernels.planner`` (per-dtype envelopes: an
 int8 KV stream budgets a deeper panel); ragged sequence lengths snap each
 block down to the largest divisor of its axis instead of asserting, and a
-degenerate snap (prime-ish lengths) falls back to the jnp oracle.
+degenerate snap (prime-ish lengths) falls back to the jnp oracle — or
+raises, under a ``strict_tiles`` policy.
+
+The per-row softmax statistics (m, l, LSE, and the backward's delta) are
+``(rows, 1)`` columns, never 1-D vectors: Mosaic tiles the last two dims of
+every block by (8, 128), and a column keeps each block's second-minor dim a
+multiple of 8 or the whole axis.
 """
 from __future__ import annotations
 
@@ -174,17 +180,17 @@ def _flash_kernel(qoff_ref, kvlen_ref, *refs, scale: float, causal: bool,
         if ok is not None:
             s = jnp.where(ok, s, NEG_INF)
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]  # (q_block, 1): row stats stay 2-D for Mosaic
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         if ok is not None:
             # explicit zero at masked slots: when a row is fully masked m_new
             # is still NEG_INF and exp(s - m_new) would be 1, silently
             # averaging v
             p = jnp.where(ok, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jnp.dot(
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
             p, v, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
@@ -193,14 +199,15 @@ def _flash_kernel(qoff_ref, kvlen_ref, *refs, scale: float, causal: bool,
     @pl.when(kb == nk - 1)
     def _emit():
         l_safe = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
         lse_ref[0] = m_ref[...] + jnp.log(l_safe)
 
 
 def _probs_from_lse(s, ok, lse):
-    """exp(s - lse) = softmax probs (lse folds the l normalizer); the
-    explicit zero guards fully-masked rows where lse ~ NEG_INF."""
-    p = jnp.exp(s - lse[:, None])
+    """exp(s - lse) = softmax probs (lse folds the l normalizer; a
+    (q_block, 1) column); the explicit zero guards fully-masked rows where
+    lse ~ NEG_INF."""
+    p = jnp.exp(s - lse)
     return p if ok is None else jnp.where(ok, p, 0.0)
 
 
@@ -226,8 +233,8 @@ def _bwd_dq_kernel(qoff_ref, kvlen_ref, q_ref, k_ref, v_ref, g_ref, lse_ref,
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         g = g_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]      # (q_block,) f32
-        delta = delta_ref[0]  # (q_block,) f32 rowsum(dO * O)
+        lse = lse_ref[0]      # (q_block, 1) f32
+        delta = delta_ref[0]  # (q_block, 1) f32 rowsum(dO * O)
 
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         ok = _mask(qoff, kvlen, qi, kb, causal=causal, window=window,
@@ -236,7 +243,7 @@ def _bwd_dq_kernel(qoff_ref, kvlen_ref, q_ref, k_ref, v_ref, g_ref, lse_ref,
             s = jnp.where(ok, s, NEG_INF)
         p = _probs_from_lse(s, ok, lse)
         dp = jnp.dot(g, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
+        ds = p * (dp - delta) * scale
         dq_acc[...] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
 
     _run_kv_block(_body, kb, kvlen, kv_block=kv_block, full_len=full_len)
@@ -290,7 +297,7 @@ def _bwd_dkv_kernel(qoff_ref, kvlen_ref, q_ref, k_ref, v_ref, g_ref, lse_ref,
         p = _probs_from_lse(s, ok, lse)
         dv_acc[...] += jnp.dot(p.T, g, preferred_element_type=jnp.float32)
         dp = jnp.dot(g, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
+        ds = p * (dp - delta) * scale
         dk_acc[...] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
 
     if live is None:
@@ -349,7 +356,7 @@ def _fwd_call(q, k, v, qoff, kvlen, kscale, vscale, *, causal, window,
 
     def row_map(g, j):
         b, i = decode(g)
-        return (b, i)
+        return (b, i, 0)
 
     in_specs = [smem, smem]
     operands = [qoff, kvlen]
@@ -370,12 +377,12 @@ def _fwd_call(q, k, v, qoff, kvlen, kscale, vscale, *, causal, window,
         grid=(bh * nq, nk_run),
         in_specs=in_specs,
         out_specs=[pl.BlockSpec((1, q_block, hd), q_map),
-                   pl.BlockSpec((1, q_block), row_map)],
+                   pl.BlockSpec((1, q_block, 1), row_map)],
         out_shape=[jax.ShapeDtypeStruct((bh, sq, hd), q.dtype),
-                   jax.ShapeDtypeStruct((bh, sq), jnp.float32)],
+                   jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32)],
         scratch_shapes=[
-            pltpu.VMEM((q_block,), jnp.float32),
-            pltpu.VMEM((q_block,), jnp.float32),
+            pltpu.VMEM((q_block, 1), jnp.float32),
+            pltpu.VMEM((q_block, 1), jnp.float32),
             pltpu.VMEM((q_block, hd), jnp.float32),
         ],
         interpret=interpret,
@@ -396,7 +403,8 @@ def _bwd_call(q, k, v, qoff, kvlen, out, lse, g, *, causal, window, q_block,
     h, kvh, n_rep = _gqa_geometry(q, k, n_heads)
     hpb = bh // rows
     khpb = kbh // rows  # kv batch-heads per decode lane
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1,
+                    keepdims=True)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
 
     dec_q = grid_decode(bh, nq)
@@ -411,7 +419,7 @@ def _bwd_call(q, k, v, qoff, kvlen, out, lse, g, *, causal, window, q_block,
 
     def row_map(g_, j):
         b, i = dec_q(g_)
-        return (b, i)
+        return (b, i, 0)
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
@@ -424,8 +432,8 @@ def _bwd_call(q, k, v, qoff, kvlen, out, lse, g, *, causal, window, q_block,
                   pl.BlockSpec((1, kv_block, hd), kv_map),
                   pl.BlockSpec((1, kv_block, hd), kv_map),
                   pl.BlockSpec((1, q_block, hd), q_map),
-                  pl.BlockSpec((1, q_block), row_map),
-                  pl.BlockSpec((1, q_block), row_map)],
+                  pl.BlockSpec((1, q_block, 1), row_map),
+                  pl.BlockSpec((1, q_block, 1), row_map)],
         out_specs=pl.BlockSpec((1, q_block, hd), q_map),
         out_shape=jax.ShapeDtypeStruct((bh, sq, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((q_block, hd), jnp.float32)],
@@ -454,7 +462,7 @@ def _bwd_call(q, k, v, qoff, kvlen, out, lse, g, *, causal, window, q_block,
 
     def row_map_t(g_, j):
         b, _ = dec_kv(g_)
-        return (_qbh(b, j), j % nq if n_rep > 1 else j)
+        return (_qbh(b, j), j % nq if n_rep > 1 else j, 0)
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
@@ -467,8 +475,8 @@ def _bwd_call(q, k, v, qoff, kvlen, out, lse, g, *, causal, window, q_block,
                   pl.BlockSpec((1, kv_block, hd), kv_map_t),
                   pl.BlockSpec((1, kv_block, hd), kv_map_t),
                   pl.BlockSpec((1, q_block, hd), q_map_t),
-                  pl.BlockSpec((1, q_block), row_map_t),
-                  pl.BlockSpec((1, q_block), row_map_t)],
+                  pl.BlockSpec((1, q_block, 1), row_map_t),
+                  pl.BlockSpec((1, q_block, 1), row_map_t)],
         out_specs=[pl.BlockSpec((1, kv_block, hd), kv_map_t),
                    pl.BlockSpec((1, kv_block, hd), kv_map_t)],
         out_shape=[jax.ShapeDtypeStruct((kbh, sk, hd), k.dtype),
@@ -525,7 +533,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     n_heads: Optional[int] = None,
                     k_scale: Optional[jax.Array] = None,
                     v_scale: Optional[jax.Array] = None,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """q: (bh, sq, hd); k, v: (kbh, sk, hd) — heads pre-folded into batch
     (batch-major: bh = batch * heads + head).  Returns (bh, sq, hd).
 
@@ -566,7 +574,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     # a degenerate snap (prime/odd axis -> sub-sublane tile on a long dim)
     # would run a catastrophically fine grid; take the jnp oracle instead
     if (q_block < 8 <= sq) or (kv_block < 8 <= sk):
-        from repro.kernels import ref
+        from repro.kernels import policy, ref
+
+        if policy.current().strict_tiles:
+            raise ValueError(f"flash_attention: blocks ({q_block}, "
+                             f"{kv_block}) degenerate for sq={sq}, sk={sk}; "
+                             "strict tiles forbid the oracle fallback")
 
         return ref.flash_attention_ref(q, k, v, causal=causal, window=window,
                                        q_offset=q_offset, kv_len=kv_len,
@@ -627,7 +640,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     fa = _flash_fn(bool(causal), int(window), q_block, kv_block, nk_run,
                    full_len, None if n_heads is None else int(n_heads),
-                   rows, quantized, bool(interpret))
+                   rows, quantized, planner.resolve_interpret(interpret))
     if quantized:
         kbh = k.shape[0]
         return fa(q, k, v, qoff_arr, kvlen_arr,

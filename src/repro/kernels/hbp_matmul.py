@@ -14,7 +14,7 @@ Tile sizes default to ``None`` = planned from the queried device through
 values to override.  Ragged shapes snap each override down to the largest
 divisor of its axis instead of asserting, and a degenerate snap (prime-ish
 dims forcing a sub-sublane tile on a long axis) falls back to the jnp
-oracle.  ``out_dtype`` lets the Strassen-schedule wrapper keep the f32
+oracle — or raises, under a ``strict_tiles`` policy.  ``out_dtype`` lets the Strassen-schedule wrapper keep the f32
 accumulator through its combination tree instead of rounding at every leaf.
 """
 from __future__ import annotations
@@ -48,11 +48,12 @@ def _mm_kernel(a_ref, b_ref, out_ref, acc_ref, *, nk: int):
                                              "interpret", "out_dtype"))
 def hbp_matmul(a: jax.Array, b: jax.Array, *, bm: Optional[int] = None,
                bn: Optional[int] = None, bk: Optional[int] = None,
-               morton: bool = True, interpret: bool = True,
+               morton: bool = True, interpret: Optional[bool] = None,
                out_dtype=None) -> jax.Array:
     """C = A @ B with Morton-ordered output tiles.  A: (m, k), B: (k, n)."""
     from repro.kernels import planner
 
+    interpret = planner.resolve_interpret(interpret)
     m, k = a.shape
     k2, n = b.shape
     assert k == k2
@@ -70,6 +71,12 @@ def hbp_matmul(a: jax.Array, b: jax.Array, *, bm: Optional[int] = None,
     # a degenerate snap (prime-ish dim -> sub-sublane tile on a long axis)
     # would run a catastrophically fine grid; take the jnp oracle instead
     if (bm < 8 <= m) or (bn < 8 <= n) or (bk < 8 <= k):
+        from repro.kernels import policy
+
+        if policy.current().strict_tiles:
+            raise ValueError(f"hbp_matmul: tiles ({bm}, {bn}, {bk}) "
+                             f"degenerate for ({m}, {k}) @ ({k}, {n}); strict "
+                             "tiles forbid the jnp.dot fallback")
         return jnp.dot(a.astype(jnp.float32),
                        b.astype(jnp.float32)).astype(out_dtype)
     nm, nn, nk = m // bm, n // bn, k // bk

@@ -30,11 +30,26 @@ from repro.core import costmodel
 # TPU vector-memory lane width (last-dim tiling) in elements.
 LANE = 128
 
-# Fallback fast-memory sizes when the backend exposes nothing better.
-# TPU: VMEM per core (v4/v5p class).  CPU: a shared L2+L3 slice — 8 MiB also
+# Fallback fast-memory sizes for hosts: CPU a shared L2+L3 slice — 8 MiB also
 # reproduces the seed's hand-tuned 512/1024 attention blocks exactly, so the
 # planner's CPU defaults are behavior-preserving.  GPU: an L2-ish slice.
-_DEFAULT_FAST_BYTES = {"tpu": 16 * 2**20, "cpu": 8 * 2**20, "gpu": 16 * 2**20}
+_DEFAULT_FAST_BYTES = {"cpu": 8 * 2**20, "gpu": 16 * 2**20}
+
+# TPU fast memory, keyed by ``device_kind``: the scoped-VMEM limit Mosaic
+# grants a kernel by default (XLA's ``--xla_tpu_scoped_vmem_limit_kib``:
+# 16 MiB through v5p, 32 MiB on v6e).  Physical VMEM is larger on some
+# chips (v5e: 128 MiB per core, ``jax/_src/pallas/mosaic/tpu_info.py``),
+# but a kernel that plans past the scoped limit is refused at compile time.
+# ``memory_stats()`` reports HBM, never VMEM, so it cannot stand in.
+TPU_VMEM_BYTES = {
+    "TPU v4": 16 * 2**20,
+    "TPU v5 lite": 16 * 2**20,
+    "TPU v5e": 16 * 2**20,
+    "TPU v5": 16 * 2**20,
+    "TPU v5p": 16 * 2**20,
+    "TPU v6 lite": 32 * 2**20,
+    "TPU v6e": 32 * 2**20,
+}
 
 # Block-transfer granularity B (bytes): HBM burst on TPU, cache line on CPU.
 _DEFAULT_LINE_BYTES = {"tpu": 512, "cpu": 64, "gpu": 128}
@@ -58,6 +73,21 @@ class DeviceParams:
         return max(32 // max(itemsize, 1), 8)
 
 
+def native() -> bool:
+    """True where Pallas kernels compile natively: a TPU backend."""
+    return jax.default_backend() == "tpu"
+
+
+def resolve_interpret(interpret: Optional[bool],
+                      supported: Optional[bool] = None) -> bool:
+    """The kernels' ``interpret`` default, resolved here only: ``None``
+    means native where the kernel compiles (``supported``; default: on a
+    TPU) and the Pallas interpreter everywhere else."""
+    if interpret is not None:
+        return bool(interpret)
+    return not (native() if supported is None else supported)
+
+
 # device_params is memoized: every dispatch trace asks for it, and
 # ``jax.devices()`` is not free.  The REPRO_FAST_BYTES value participates in
 # the cache key so flipping the env var takes effect without a clear, but
@@ -68,9 +98,11 @@ _DP_CACHE: dict = {}
 
 def device_params(device=None) -> DeviceParams:
     """Query the current device (memoized).  ``REPRO_FAST_BYTES`` overrides
-    the fast-memory size (useful to replay a plan for a different machine);
-    otherwise ``device.memory_stats()`` is consulted when the backend
-    exposes it, falling back to the per-platform defaults."""
+    the fast-memory size (useful to replay a plan for a different machine).
+    A TPU reads :data:`TPU_VMEM_BYTES` by its ``device_kind`` and raises on
+    a kind the table lacks; other platforms consult
+    ``device.memory_stats()`` when the backend exposes it, falling back to
+    the per-platform defaults."""
     env = os.environ.get("REPRO_FAST_BYTES")
     cache_key = (device, env)
     try:
@@ -82,6 +114,11 @@ def device_params(device=None) -> DeviceParams:
     kind = getattr(dev, "device_kind", platform)
     if env:
         fast = int(env)
+    elif platform == "tpu":
+        if kind not in TPU_VMEM_BYTES:
+            raise ValueError(f"no fast-memory size known for TPU kind "
+                             f"{kind!r}; add it to planner.TPU_VMEM_BYTES")
+        fast = TPU_VMEM_BYTES[kind]
     else:
         fast = (_queried_fast_bytes(dev, platform)
                 or _DEFAULT_FAST_BYTES.get(platform, 8 * 2**20))
@@ -160,10 +197,67 @@ def divisor_tile(dim: int, cap: int, multiple: int = 1) -> int:
     return 1
 
 
+def _tile(dim: int, cap: int, multiple: int, dp: DeviceParams) -> int:
+    """:func:`divisor_tile`, held to the TPU's block rule: a block dimension
+    is a multiple of its hardware tiling or the whole axis.  Where no
+    aligned divisor fits under ``cap``, a TPU plan takes the whole axis
+    (:func:`_fit` then checks it against fast memory) instead of a
+    misaligned divisor Mosaic would refuse."""
+    t = divisor_tile(dim, cap, multiple)
+    if dp.platform == "tpu" and t % multiple and t != dim:
+        return dim
+    return t
+
+
 def _budget(dp: DeviceParams) -> int:
     # One third of fast memory: leave headroom for double buffering and the
     # out tile, mirroring the paper's constant-factor slack in Lemma 4.4.
     return max(dp.fast_bytes // 3, 1024)
+
+
+# ---------------------------------------------------------------------------
+# working sets: the resident bytes of one grid step, per op.  Every plan
+# below is checked against ``dp.fast_bytes`` with these (``_fit``), and the
+# autotune ladder filters its candidates with the same functions.
+# ---------------------------------------------------------------------------
+
+def scan_row_block(rows: int) -> int:
+    """Rows per scan grid step: 8 (one f32 sublane tile) when the row count
+    allows, else every row — either meets the TPU's (8, 128) layout rule."""
+    return 8 if rows % 8 == 0 else rows
+
+
+def scan_working_set(block: int, itemsize: int, rows: int) -> int:
+    """in, out, local and offset buffers of one scan leaf over ``rows``
+    input rows."""
+    return 4 * block * itemsize * scan_row_block(rows)
+
+
+def matmul_working_set(bm: int, bn: int, bk: int, itemsize: int) -> int:
+    """A and B panels in the operand dtype plus the f32 accumulator."""
+    return (bm * bk + bk * bn) * itemsize + 4 * bm * bn
+
+
+def transpose_working_set(bt: int, itemsize: int) -> int:
+    """The in tile and the out tile."""
+    return 2 * bt * bt * itemsize
+
+
+def attention_working_set(q_block: int, kv_block: int, hd: int,
+                          itemsize: int, kv_itemsize: int) -> int:
+    """q rows + f32 acc rows, k/v panels (kv width), the f32 P tile, and the
+    (m, l) columns."""
+    return (q_block * hd * (itemsize + 4) + 2 * kv_block * hd * kv_itemsize
+            + 4 * q_block * kv_block + 8 * q_block)
+
+
+def _fit(op: str, plan: dict, ws: int, dp: DeviceParams) -> dict:
+    """Return ``plan`` if its working set fits the device's fast memory;
+    raise when even this (smallest legal) plan does not."""
+    if ws > dp.fast_bytes:
+        raise ValueError(f"{op} plan {plan} needs {ws} B of fast memory; "
+                         f"{dp.kind} has {dp.fast_bytes} B")
+    return plan
 
 
 # ---------------------------------------------------------------------------
@@ -172,12 +266,17 @@ def _budget(dp: DeviceParams) -> int:
 
 def plan_scan(shape, dtype, dp: Optional[DeviceParams] = None) -> dict:
     """BP leaf size for the two-pass prefix scan: the largest lane-aligned
-    block whose 4 resident buffers (in, out, local, offset) fit the envelope."""
+    block whose 4 resident buffers (in, out, local, offset) of
+    :func:`scan_row_block` rows fit the envelope."""
     dp = dp or device_params()
     n = shape[-1]
+    rows = shape[0] if len(shape) > 1 else 1
     itemsize = jnp.dtype(dtype).itemsize
-    cap = _pow2_floor(max(_budget(dp) // (4 * itemsize), 1))
-    return {"block": divisor_tile(n, cap, dp.lane)}
+    cap = _pow2_floor(max(_budget(dp) // scan_working_set(1, itemsize, rows),
+                          1))
+    plan = {"block": _tile(n, cap, dp.lane, dp)}
+    return _fit("scan", plan, scan_working_set(plan["block"], itemsize, rows),
+                dp)
 
 
 # dtypes the Strassen schedule may serve: the 18 extra adds per level are
@@ -201,10 +300,11 @@ def strassen_cutoff(dtype, dp: Optional[DeviceParams] = None) -> int:
 
 def plan_matmul_backend(m: int, k: int, n: int, dtype,
                         dp: Optional[DeviceParams] = None) -> dict:
-    """Matmul backend choice by the costmodel envelopes: ``strassen`` (plus
-    its recursion ``cutoff``) when the shape is square with pow2-friendly
-    halving down to the modeled crossover edge and the dtype tolerates the
-    extra adds (fp32 / bf16-with-f32-acc); ``classical`` otherwise."""
+    """The schedule a call that asks for Strassen runs, by the costmodel
+    envelopes: ``strassen`` (plus its recursion ``cutoff``) when the shape
+    is square with pow2-friendly halving down to the modeled crossover edge
+    and the dtype tolerates the extra adds (fp32 / bf16-with-f32-acc);
+    ``classical`` otherwise.  :func:`plan_matmul` never asks: see there."""
     dp = dp or device_params()
     if not (m == k == n and jnp.dtype(dtype).name in _STRASSEN_DTYPES):
         return {"backend": "classical"}
@@ -223,10 +323,14 @@ def plan_matmul_backend(m: int, k: int, n: int, dtype,
 def plan_matmul(m: int, k: int, n: int, dtype,
                 dp: Optional[DeviceParams] = None) -> dict:
     """Square (bm, bn, bk) tiles from the O(sqrt M) envelope: two operand
-    tiles in ``dtype`` plus the f32 accumulator must fit the budget.  The
-    plan also carries the envelope-selected ``backend`` ("classical" |
-    "strassen" + recursion ``cutoff``); the registry's matmul entry point
-    resolves the variant at dispatch."""
+    tiles in ``dtype`` plus the f32 accumulator must fit the budget.
+
+    The schedule is classical unless the caller asks for Strassen (the
+    ``backend="strassen"`` kwarg, or the policy variant
+    ``matmul=pallas:backend=strassen``; :func:`plan_matmul_backend` then
+    gates it).  Strassen's quadrant sums add row i to row i + m/2, so where
+    rows are independent tokens — of different requests, in a serving
+    batch — one token's result would depend on what shares its launch."""
     dp = dp or device_params()
     itemsize = jnp.dtype(dtype).itemsize
     # bytes(t) = 2 t^2 itemsize (A, B panels) + 4 t^2 (f32 acc)
@@ -234,12 +338,12 @@ def plan_matmul(m: int, k: int, n: int, dtype,
     t = _pow2_floor(edge)
     sub = dp.sublane(dtype)
     plan = {
-        "bm": divisor_tile(m, t, sub),
-        "bn": divisor_tile(n, t, dp.lane),
-        "bk": divisor_tile(k, t, dp.lane),
+        "bm": _tile(m, t, sub, dp),
+        "bn": _tile(n, t, dp.lane, dp),
+        "bk": _tile(k, t, dp.lane, dp),
     }
-    plan.update(plan_matmul_backend(m, k, n, dtype, dp))
-    return plan
+    return _fit("matmul", plan, matmul_working_set(plan["bm"], plan["bn"],
+                                                   plan["bk"], itemsize), dp)
 
 
 def plan_transpose(m: int, n: int, dtype,
@@ -250,7 +354,9 @@ def plan_transpose(m: int, n: int, dtype,
     itemsize = jnp.dtype(dtype).itemsize
     t = _pow2_floor(costmodel.oblivious_tile_edge(_budget(dp), 2, itemsize))
     g = math.gcd(m, n) if m != n else m
-    return {"bt": divisor_tile(g, t, dp.lane)}
+    plan = {"bt": _tile(g, t, dp.lane, dp)}
+    return _fit("transpose", plan, transpose_working_set(plan["bt"], itemsize),
+                dp)
 
 
 # a query block this short (decode / speculative lookahead) flips the plan
@@ -286,16 +392,19 @@ def plan_attention(sq: int, sk: int, hd: int, dtype,
     if sq <= DECODE_MAX_SQ and sk > sq:
         per_row = 2 * hd * kv_item + 4 * sq + 4  # k/v rows + P col + l bits
         kb = _pow2_floor(max(budget // per_row, 1))
-        return {"q_block": sq,
-                "kv_block": divisor_tile(sk, kb, kv_sub)}
-    # q row + f32 acc row + k/v rows (kv width) + (m, l)
-    c1 = hd * (itemsize + 2 * kv_item + 4) + 8
-    t = int((-c1 + math.sqrt(c1 * c1 + 16.0 * budget)) / 8.0)
-    t = _pow2_floor(max(t, 1))
-    sub = dp.sublane(dtype)
-    qb = divisor_tile(sq, t, sub)
-    kb = divisor_tile(sk, 2 * t, kv_sub)  # kv stream gets the deeper panel
-    return {"q_block": qb, "kv_block": kb}
+        plan = {"q_block": sq, "kv_block": _tile(sk, kb, kv_sub, dp)}
+    else:
+        # q row + f32 acc row + k/v rows (kv width) + (m, l)
+        c1 = hd * (itemsize + 2 * kv_item + 4) + 8
+        t = int((-c1 + math.sqrt(c1 * c1 + 16.0 * budget)) / 8.0)
+        t = _pow2_floor(max(t, 1))
+        sub = dp.sublane(dtype)
+        plan = {"q_block": _tile(sq, t, sub, dp),
+                # the kv stream gets the deeper panel
+                "kv_block": _tile(sk, 2 * t, kv_sub, dp)}
+    ws = attention_working_set(plan["q_block"], plan["kv_block"], hd,
+                               itemsize, kv_item)
+    return _fit("attention", plan, ws, dp)
 
 
 def plan_fft(n: int, dp: Optional[DeviceParams] = None) -> dict:

@@ -41,8 +41,6 @@ import warnings
 from dataclasses import dataclass
 from typing import Callable, Optional, Tuple
 
-import jax
-
 from repro.kernels import planner, policy, ref
 from repro.kernels.bi_fft import bi_fft
 from repro.kernels.bi_transpose import bi_transpose
@@ -51,15 +49,12 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.strassen_matmul import matmul as backend_matmul
 
 
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @dataclass(frozen=True)
 class KernelSpec:
     """One registered op.
 
-    ``plan(*arrays) -> dict`` produces the tile kwargs for the Pallas path;
+    ``plan(*arrays, dp=None) -> dict`` produces the tile kwargs for the
+    Pallas path, planned for ``dp`` (default: the queried device);
     ``pallas_only`` names the kwargs (tiles + schedule flags) that must be
     stripped before calling the oracle, which takes semantic kwargs only.
     ``supported() -> bool`` says whether the Pallas path compiles natively
@@ -79,7 +74,7 @@ class KernelSpec:
     ref: Callable
     plan: Callable
     pallas_only: Tuple[str, ...] = ()
-    supported: Callable[[], bool] = on_tpu
+    supported: Callable[[], bool] = planner.native
     has_vjp: bool = False
     needs: Optional[Callable[..., bool]] = None
     simulator: Optional[Callable] = None
@@ -187,6 +182,10 @@ def dispatch(name: str, *args, impl: Optional[str] = None,
     # what the kernel takes"
     if (not forced and impl == "pallas" and spec.needs is not None
             and not spec.needs(**kwargs)):
+        if pol.strict_tiles:
+            raise ValueError(f"dispatch({name!r}): the kernel cannot take "
+                             "this call's context; strict tiles forbid the "
+                             "oracle fallback")
         impl = "ref"
     explicit = {k: kwargs.pop(k) for k in list(kwargs) if k in spec.pallas_only}
     explicit = {k: v for k, v in explicit.items() if v is not None}
@@ -216,8 +215,10 @@ def dispatch(name: str, *args, impl: Optional[str] = None,
         # between the explicit call arg and the policy-global flag
         interpret = pol.variant_for(name).get("interpret")
     if interpret is None:
-        interpret = pol.interpret if pol.interpret is not None else not native
-    return spec.pallas(*args, interpret=interpret, **kwargs, **tiles)
+        interpret = pol.interpret
+    return spec.pallas(*args,
+                       interpret=planner.resolve_interpret(interpret, native),
+                       **kwargs, **tiles)
 
 
 # ---------------------------------------------------------------------------
@@ -276,7 +277,7 @@ register(KernelSpec(
     name="scan",
     pallas=bp_scan,
     ref=ref.bp_scan_ref,
-    plan=lambda x: planner.plan_scan(x.shape, x.dtype),
+    plan=lambda x, dp=None: planner.plan_scan(x.shape, x.dtype, dp),
     pallas_only=("block",),
     simulator=_sim_scan,
 ))
@@ -288,8 +289,8 @@ register(KernelSpec(
     # carries a custom VJP (dA = g B^T, dB = A^T g through the same kernels)
     pallas=backend_matmul,
     ref=ref.matmul_ref,
-    plan=lambda a, b: planner.plan_matmul(a.shape[0], a.shape[1], b.shape[1],
-                                          a.dtype),
+    plan=lambda a, b, dp=None: planner.plan_matmul(
+        a.shape[0], a.shape[1], b.shape[1], a.dtype, dp),
     pallas_only=("bm", "bn", "bk", "morton", "backend", "cutoff"),
     has_vjp=True,
     simulator=_sim_matmul,
@@ -299,7 +300,8 @@ register(KernelSpec(
     name="transpose",
     pallas=bi_transpose,
     ref=ref.transpose_ref,
-    plan=lambda x: planner.plan_transpose(x.shape[0], x.shape[1], x.dtype),
+    plan=lambda x, dp=None: planner.plan_transpose(x.shape[0], x.shape[1],
+                                                   x.dtype, dp),
     pallas_only=("bt", "morton"),
     simulator=_sim_transpose,
 ))
@@ -309,9 +311,8 @@ register(KernelSpec(
     pallas=flash_attention,
     ref=ref.flash_attention_ref,
     # per-dtype envelopes: an int8 KV cache budgets a deeper panel
-    plan=lambda q, k, v: planner.plan_attention(q.shape[1], k.shape[1],
-                                                q.shape[2], q.dtype,
-                                                kv_dtype=k.dtype),
+    plan=lambda q, k, v, dp=None: planner.plan_attention(
+        q.shape[1], k.shape[1], q.shape[2], q.dtype, dp, kv_dtype=k.dtype),
     pallas_only=("q_block", "kv_block"),
     # recomputation-style backward kernels (dq + dk/dv) registered as a
     # custom VJP in flash_attention — training no longer routes around it
@@ -327,7 +328,7 @@ register(KernelSpec(
     name="fft",
     pallas=bi_fft,
     ref=ref.fft_ref,
-    plan=lambda x: planner.plan_fft(x.shape[-1]),
+    plan=lambda x, dp=None: planner.plan_fft(x.shape[-1], dp),
     pallas_only=("n1",),
     simulator=_sim_fft,
 ))

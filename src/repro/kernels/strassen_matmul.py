@@ -13,11 +13,11 @@ combinations (A11 + A22 etc.) are formed as fused jnp adds feeding the leaf
 ``pallas_call``s, quadrant combines stay in f32, and only the final result
 rounds to the input dtype.
 
-``matmul`` is the registry's dispatch entry point: it resolves the
-planner's ``backend`` field ("classical" | "strassen"), and registers a
-custom VJP (dA = g Bᵀ, dB = Aᵀ g, each re-planned for its own — possibly
-crossover-flipped — shape), so model matmuls can route through the kernels
-under autodiff.
+``matmul`` is the registry's dispatch entry point: it runs the requested
+``backend`` ("classical", the default, or "strassen", gated per shape by
+``planner.plan_matmul_backend``), and registers a custom VJP (dA = g Bᵀ,
+dB = Aᵀ g, each a classical product), so model matmuls can route through
+the kernels under autodiff.
 """
 from __future__ import annotations
 
@@ -58,7 +58,7 @@ def _combo(parts, idxs, signs, out_dtype):
 def strassen_matmul(a: jax.Array, b: jax.Array, *,
                     cutoff: Optional[int] = None, bm: Optional[int] = None,
                     bn: Optional[int] = None, bk: Optional[int] = None,
-                    morton: bool = True, interpret: bool = True) -> jax.Array:
+                    morton: bool = True, interpret: Optional[bool] = None) -> jax.Array:
     """C = A @ B via the Strassen quadrant recursion, classical tiled leaves.
 
     Ineligible shapes (non-square, or nothing to halve above the cutoff)
@@ -100,14 +100,15 @@ def strassen_matmul(a: jax.Array, b: jax.Array, *,
 
 
 def _run(a, b, backend, cutoff, bm, bn, bk, morton, interpret):
-    """Resolve the backend (None = ask the planner) and run the variant."""
-    if backend is None:
+    """Run the variant: classical unless ``backend`` asks for Strassen; a
+    Strassen request without a ``cutoff`` takes the planner's gate and
+    cutoff for this shape."""
+    if backend == "strassen" and cutoff is None:
         from repro.kernels import planner
 
-        plan = planner.plan_matmul(a.shape[0], a.shape[1], b.shape[1], a.dtype)
-        backend = plan["backend"]
-        if cutoff is None:
-            cutoff = plan.get("cutoff")
+        plan = planner.plan_matmul_backend(a.shape[0], a.shape[1],
+                                           b.shape[1], a.dtype)
+        backend, cutoff = plan["backend"], plan.get("cutoff")
     if backend == "strassen":
         return strassen_matmul(a, b, cutoff=cutoff, bm=bm, bn=bn, bk=bk,
                                morton=morton, interpret=interpret)
@@ -118,9 +119,8 @@ def _run(a, b, backend, cutoff, bm, bn, bk, morton, interpret):
 @functools.lru_cache(maxsize=None)
 def _vjp_matmul(backend, cutoff, bm, bn, bk, morton, interpret):
     """custom-VJP wrapper per static config: the forward runs the selected
-    variant; the backward's two products re-enter ``_run`` with
-    ``backend=None`` so each gradient matmul gets its *own* planner verdict
-    (g Bᵀ and Aᵀ g may sit on the other side of the crossover)."""
+    variant; the backward's two products (g Bᵀ and Aᵀ g) run classical,
+    planner-tiled for their own shapes."""
 
     @jax.custom_vjp
     def f(a, b):
@@ -142,10 +142,10 @@ def _vjp_matmul(backend, cutoff, bm, bn, bk, morton, interpret):
 def matmul(a: jax.Array, b: jax.Array, *, backend: Optional[str] = None,
            cutoff: Optional[int] = None, bm: Optional[int] = None,
            bn: Optional[int] = None, bk: Optional[int] = None,
-           morton: bool = True, interpret: bool = True) -> jax.Array:
+           morton: bool = True, interpret: Optional[bool] = None) -> jax.Array:
     """Backend-dispatching matmul (the registry's ``matmul`` Pallas entry):
-    ``backend`` None asks the planner; "classical" runs ``hbp_matmul``,
-    "strassen" the quadrant recursion.  Differentiable (custom VJP)."""
+    ``backend`` None or "classical" runs ``hbp_matmul``, "strassen" the
+    quadrant recursion.  Differentiable (custom VJP)."""
     if backend not in (None, "classical", "strassen"):
         raise ValueError(f"unknown matmul backend {backend!r}; expected "
                          "'classical' or 'strassen'")

@@ -1,17 +1,24 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell with
 ShapeDtypeStruct inputs (no allocation), record memory/cost/collective
-analysis.  The two lines above MUST stay first: jax locks the device count on
-first init.
+analysis.  The environment lines below MUST run before jax is imported: jax
+locks the platform and the device count on first init.  They pin this
+process, and the ``--all`` children that inherit its environment, to the
+CPU's 512 virtual devices — a dry-run never takes a chip — and add to
+``XLA_FLAGS`` rather than replace it.
 
 Usage:
   python -m repro.launch.dryrun --arch qwen3-32b --shape train_4k --mesh single
   python -m repro.launch.dryrun --all            # every cell, subprocess each
   python -m repro.launch.dryrun --all --mesh multi
 """
-import argparse
+import os
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (
+    os.environ.get("XLA_FLAGS", "")
+    + " --xla_force_host_platform_device_count=512").strip()
+
+import argparse  # noqa: E402
 import gzip
 import json
 import subprocess
@@ -220,6 +227,8 @@ def main() -> int:
     ap.add_argument("--timeout", type=int, default=1800)
     ap.add_argument("--no-hlo", action="store_true")
     args = ap.parse_args()
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
 
     if args.impl:
         from repro.kernels import policy

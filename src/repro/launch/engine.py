@@ -62,7 +62,9 @@ taxonomy, mapped onto launches):
     per-tensor migration; caches rebuild empty and requests replay).
 
 (b) *Transient launch fault / poisoned row.*  Every launch runs under
-    bounded retry with exponential backoff and seeded jitter (the one
+    bounded retry — of faults only: an injected one or an error the device
+    reports at run time; a program error (a trace or compile failure)
+    propagates at once — with exponential backoff and seeded jitter (the one
     sanctioned nondeterminism — it perturbs wall time, never tokens;
     retries are sound because faults fire BEFORE the launch commits its
     donated buffers).  A row whose logits go non-finite is bisected by the
@@ -128,6 +130,7 @@ from repro.launch.serve import Request, Server
 from repro.models import cache as dcache
 from repro.models.base import Model, RunOptions, UnsupportedFamilyError
 from repro.runtime.fault_tolerance import (
+    RETRYABLE_FAULTS,
     FaultInjector,
     FaultPolicy,
     LaunchFailedError,
@@ -313,6 +316,10 @@ class Engine(Server):
             functools.partial(chunk_step, first=True), donate_argnums=(4,))
         self._chunk_cont = jax.jit(
             functools.partial(chunk_step, first=False), donate_argnums=(4,))
+        # each launch compiles ONCE for the engine's fixed shapes, outside
+        # the failure model (see :meth:`_program`); name -> executable
+        self._programs: dict = {}
+        self.compile_s: dict[str, float] = {}
         self.begin([])  # stats()/adopt() are valid before the first run
 
     @classmethod
@@ -360,14 +367,63 @@ class Engine(Server):
         self.slots[i] = _Slot()
         self.scheduler.counters["evictions"] += 1
 
+    # -- compiled launches ---------------------------------------------------
+    _LAUNCHES = {"decode": "_decode_rows", "prefill_first": "_chunk_first",
+                 "prefill_cont": "_chunk_cont"}
+
+    def _program(self, name: str, args):
+        """The compiled executable of launch ``name``, built on first use.
+        Compiling happens here, outside :meth:`_launch`'s retry loop: a
+        trace, lowering or compile error (a kernel the chip's compiler
+        refuses, a program that does not fit device memory) is a program
+        error and propagates at once — it is never retried, and never
+        reaches the fleet tier as a replica death.  The executable accepts
+        only the shapes and shardings it was compiled for, so serving can
+        never silently recompile."""
+        prog = self._programs.get(name)
+        if prog is None:
+            t0 = time.perf_counter()
+            prog = getattr(self, self._LAUNCHES[name]).lower(*args).compile()
+            self.compile_s[name] = time.perf_counter() - t0
+            self._programs[name] = prog
+        return prog
+
+    def _launch_args(self, name: str):
+        """Parked (all-rows-idle) arguments of launch ``name`` at the
+        engine's fixed shapes — what :meth:`compile` lowers with."""
+        b = self.max_batch
+        if name == "decode":
+            return (self.params, jnp.zeros((b, 1), jnp.int32),
+                    jnp.zeros((b,), jnp.int32), self.cache,
+                    jnp.zeros((b,), bool))
+        extras = None
+        if name == "prefill_first" and self._extras_host is not None:
+            extras = {k: jnp.asarray(v) for k, v in self._extras_host.items()}
+        return (self.params, jnp.zeros((b, self.chunk), jnp.int32),
+                jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
+                self.cache, extras)
+
+    def compile(self) -> dict[str, float]:
+        """Compile all three launches ahead of serving (nothing runs).
+        Returns the compile seconds per launch."""
+        with self.mesh, axis_rules(self.rules, self.mesh):
+            for name in self._LAUNCHES:
+                self._program(name, self._launch_args(name))
+        return dict(self.compile_s)
+
     # -- fault handling ------------------------------------------------------
-    def _launch(self, kind: str, fn, *args):
-        """Run one jitted launch under the failure model: the injector may
-        raise or delay it, failures retry up to ``FaultPolicy.max_retries``
+    def _launch(self, kind: str, name: str, *args):
+        """Run one compiled launch under the failure model: the injector may
+        raise or delay it, faults retry up to ``FaultPolicy.max_retries``
         with seeded exponential backoff, and the watchdog z-scores its wall
-        time.  Retrying the same arguments is sound because faults fire
-        before the launch commits its donated buffers.  Exhausted retries
-        escalate as :class:`LaunchFailedError` (failure model (a))."""
+        time.  Only faults retry — an injected one, or an error the device
+        reports at run time (``RETRYABLE_FAULTS``); anything else is a
+        program error and propagates on the first attempt.  Retrying the
+        same arguments is sound because faults fire before the launch
+        commits its donated buffers.  Exhausted retries escalate as
+        :class:`LaunchFailedError` (failure model (a))."""
+        t_start = time.time()
+        prog = self._program(name, args)
         ordinal = self._launch_seq[kind]
         self._launch_seq[kind] = ordinal + 1
         counters = self.scheduler.counters
@@ -377,11 +433,13 @@ class Engine(Server):
                 counters["retries"] += 1
                 time.sleep(self.fault_policy.backoff(attempt - 1,
                                                      self._fault_rng))
-            t0 = time.time()
+            # the watchdog sees a launch's wall time as a jit call would
+            # show it: a first launch includes its compile
+            t0 = t_start if attempt == 0 else time.time()
             try:
                 self.injector.before_launch(kind, ordinal)
-                out = fn(*args)
-            except Exception as e:  # noqa: BLE001 — any launch fault retries
+                out = prog(*args)
+            except RETRYABLE_FAULTS as e:
                 last = e
                 self._note_fault()
                 log.warning("%s launch %d attempt %d failed: %r",
@@ -518,8 +576,8 @@ class Engine(Server):
                  if s.state == "prefill" and s.filled == 0]
         cont = [i for i, s in enumerate(self.slots)
                 if s.state == "prefill" and s.filled > 0]
-        for group, fn in ((first, self._chunk_first),
-                          (cont, self._chunk_cont)):
+        for group, name in ((first, "prefill_first"),
+                            (cont, "prefill_cont")):
             if not group:
                 continue
             toks = np.zeros((self.max_batch, self.chunk), np.int32)
@@ -534,11 +592,11 @@ class Engine(Server):
                 else:  # park: overwritten before anything attends it
                     offset[i] = s.context
             extras = None
-            if fn is self._chunk_first and self._extras_host is not None:
+            if name == "prefill_first" and self._extras_host is not None:
                 extras = {k: jnp.asarray(v)
                           for k, v in self._extras_host.items()}
             nxt, self.cache = self._launch(
-                "prefill", fn, self.params, jnp.asarray(toks),
+                "prefill", name, self.params, jnp.asarray(toks),
                 jnp.asarray(offset), jnp.asarray(lens), self.cache, extras)
             nxt = np.asarray(nxt)
             self._n_chunks += 1
@@ -578,7 +636,7 @@ class Engine(Server):
         poison = np.zeros((self.max_batch,), bool)
         poison[self.injector.poison_rows(decoding)] = True
         nxt, ok, self.cache = self._launch(
-            "decode", self._decode_rows, self.params, jnp.asarray(toks),
+            "decode", "decode", self.params, jnp.asarray(toks),
             jnp.asarray(pos), self.cache, jnp.asarray(poison))
         nxt, ok = np.asarray(nxt), np.asarray(ok)
         self._n_decode_steps += 1
@@ -718,7 +776,6 @@ class Engine(Server):
             "work_remaining": self.work_remaining_total(),
             "launches": dict(self._launch_seq),
             "busy_s": self.busy_s,
-            "decode_compilations": self._decode_rows._cache_size(),
         }
 
     # -- fleet-tier request movement -----------------------------------------
@@ -807,33 +864,44 @@ class Engine(Server):
         }
 
 
+def lockstep_tokens(engine: Engine, r: Request, *,
+                    logits_at: Optional[int] = None):
+    """Request ``r`` run ALONE through the lockstep jitted path: its greedy
+    tokens, and — with ``logits_at`` a step index — the (V,) logits that
+    step's token was drawn from (else None)."""
+    out, kept = [], None
+    batch = {"tokens": jnp.asarray(r.prompt)[None]}
+    if r.extras:
+        for key, val in r.extras.items():
+            batch[key] = jnp.asarray(val)[None]
+    with engine.mesh, axis_rules(engine.rules, engine.mesh):
+        logits, cache = engine._prefill(engine.params, batch)
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        for step in range(r.max_new):
+            if step == logits_at:
+                kept = np.asarray(logits[0], np.float32)
+            tok = int(nxt[0])
+            out.append(tok)
+            if engine.eos_id is not None and tok == engine.eos_id:
+                break
+            if len(out) >= r.max_new:
+                break
+            pos = jnp.asarray(len(r.prompt) + step, jnp.int32)
+            nxt, logits, cache = engine._decode(engine.params, nxt[:, None],
+                                                pos, cache)
+    return out, kept
+
+
 def check_lockstep_parity(engine: Engine, requests: list[Request]) -> bool:
     """Row-for-row acceptance check: each request run ALONE through the
     lockstep jitted path must reproduce the engine's tokens exactly."""
     ok = True
     for r in requests:
-        alone = Request(r.uid, r.prompt, max_new=r.max_new)
-        batch = {"tokens": jnp.asarray(r.prompt)[None]}
-        if r.extras:
-            for key, val in r.extras.items():
-                batch[key] = jnp.asarray(val)[None]
-        with engine.mesh, axis_rules(engine.rules, engine.mesh):
-            logits, cache = engine._prefill(engine.params, batch)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            for step in range(r.max_new):
-                tok = int(nxt[0])
-                alone.out.append(tok)
-                if engine.eos_id is not None and tok == engine.eos_id:
-                    break
-                if len(alone.out) >= r.max_new:
-                    break
-                pos = jnp.asarray(len(r.prompt) + step, jnp.int32)
-                nxt, cache = engine._decode(engine.params, nxt[:, None], pos,
-                                            cache)
-        if alone.out != r.out:
+        alone, _ = lockstep_tokens(engine, r)
+        if alone != r.out:
             ok = False
             log.error("parity FAIL uid=%d alone=%s engine=%s", r.uid,
-                      alone.out, r.out)
+                      alone, r.out)
     return ok
 
 
@@ -872,6 +940,8 @@ def main():
                     help="execution-policy impl map (see serve.py docstring)")
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO, format="%(message)s")
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
 
     if args.impl:
         from repro.kernels import policy

@@ -65,14 +65,23 @@ provenance rows in the router telemetry.
 kills replica 1 only.  Respawned/joining replicas always get a CLEAN
 injector — the plan names the fleet's initial replicas.
 
-**Fleet clock.**  On this one-device test rig replicas time-share the
-device, so the router steps them round-robin and each engine accrues wall
-time on its own ``busy_s`` clock.  In production every replica is its own
-accelerator and the rounds overlap, so fleet throughput is reported
-against the MAKESPAN ``max(busy_s)`` (``fleet_tok_per_s``) — the
-machine-checkable ratio the bench records — alongside the raw sequential
-wall (``tok_per_s``), which on a single shared device cannot show the
-fleet win and is kept for honesty.
+**Devices.**  Each replica gets a mesh of its own: ``meshes`` is one mesh
+per replica (``launch.mesh.replica_meshes`` splits the visible devices
+evenly, so four chips give four one-chip replicas), or a single mesh that
+every replica shares — the one-device test rig.  A respawn comes up on its
+dead predecessor's devices.  Program errors (``ValueError``, a refused
+compile) are not deaths: they propagate out of :meth:`Router.run` at once,
+and a death beyond the ``max_restarts`` budget stops the run with an
+error chained to its ``LaunchFailedError`` — the fleet never respawns
+forever.
+
+**Fleet clock.**  The router steps its replicas round-robin from one
+thread, and each engine accrues wall time on its own ``busy_s`` clock.
+When every replica is its own accelerator the rounds could overlap, so
+fleet throughput is reported against the MAKESPAN ``max(busy_s)``
+(``fleet_tok_per_s``) — the machine-checkable ratio the bench records —
+alongside the raw sequential wall (``tok_per_s``), which on a single
+shared device cannot show the fleet win and is kept for honesty.
 """
 from __future__ import annotations
 
@@ -102,16 +111,28 @@ class Router:
     queue, two routing arms, snapshot migration, death → checkpoint-streamed
     respawn).  See the module docstring for the full contract."""
 
-    def __init__(self, cfg, mesh, *, n_replicas: int = 2, route: str = "pws",
-                 seed: int = 0, ckpt_dir=None, fleet_faults: str = "",
-                 queue_depth: int = 1,
+    # replica respawns one run may make; the next death stops the run
+    max_restarts = 4
+
+    def __init__(self, cfg, meshes=None, *, n_replicas: int = 2,
+                 route: str = "pws", seed: int = 0, ckpt_dir=None,
+                 fleet_faults: str = "", queue_depth: int = 1,
                  rebalance_threshold: Optional[int] = None,
                  respawn: bool = True, **engine_kw):
         if route not in ("pws", "rws"):
             raise ValueError(f"unknown routing arm {route!r}: "
                              "expected 'pws' or 'rws'")
+        from repro.launch.mesh import replica_meshes
+
+        if meshes is None:
+            meshes = replica_meshes(n_replicas)
+        elif not isinstance(meshes, (list, tuple)):
+            meshes = [meshes] * n_replicas  # one shared mesh
+        if len(meshes) != n_replicas:
+            raise ValueError(f"{len(meshes)} meshes for {n_replicas} "
+                             "replicas")
         self.cfg = cfg
-        self.mesh = mesh
+        self.mesh = meshes[0]
         self.route = route
         self.seed = int(seed)
         self.queue_depth = int(queue_depth)
@@ -126,15 +147,15 @@ class Router:
         # through Engine.restart, so all replicas serve identical logits
         self.ckpt_dir = ckpt_dir or tempfile.mkdtemp(prefix="repro-router-")
         self.replicas: list[Replica] = [
-            spawn_replica(0, cfg, mesh, None,
+            spawn_replica(0, cfg, meshes[0], None,
                           injector=FaultInjector(plans[0]), **engine_kw)]
         from repro.checkpoint import save_checkpoint
         save_checkpoint(self.ckpt_dir, 0,
                         {"params": self.replicas[0].engine.params},
-                        mesh_shape=dict(mesh.shape))
+                        mesh_shape=dict(meshes[0].shape))
         for rid in range(1, n_replicas):
             self.replicas.append(
-                spawn_replica(rid, cfg, mesh, self.ckpt_dir,
+                spawn_replica(rid, cfg, meshes[rid], self.ckpt_dir,
                               injector=FaultInjector(plans[rid]),
                               **engine_kw))
         self._by_rid = {r.rid: r for r in self.replicas}
@@ -374,15 +395,22 @@ class Router:
         self.counters["requeued_on_death"] += len(salvaged)
         log.warning("replica %d died (%s): %d request(s) re-queued fleet-wide",
                     rep.rid, err, len(salvaged))
-        if self.respawn:
-            self.add_replica(_counter="replica_restarts")
+        if not self.respawn:
+            return
+        if self.counters["replica_restarts"] >= self.max_restarts:
+            raise RuntimeError(
+                f"replica {rep.rid} died with the restart budget "
+                f"({self.max_restarts}) spent") from err
+        self.add_replica(respawn_mesh(rep.engine.mesh),
+                         _counter="replica_restarts")
 
     def add_replica(self, mesh=None, *, _counter: str = "joins") -> Replica:
         """Elastic join (and the respawn path): spin a new replica from the
         fleet checkpoint through ``Engine.restart`` on ``mesh`` — default
-        ``elastic.respawn_mesh`` of the fleet mesh (same device count, or
-        shrunken when the dead replica took hosts with it).  Joiners and
-        respawns always get a clean injector."""
+        ``elastic.respawn_mesh`` of replica 0's mesh; a respawn passes its
+        dead predecessor's (same devices, or fewer when the dead replica
+        took hosts with it).  Joiners and respawns always get a clean
+        injector."""
         rid = self._next_rid
         self._next_rid += 1
         rep = spawn_replica(rid, self.cfg, mesh or respawn_mesh(self.mesh),
@@ -444,6 +472,8 @@ def main():
                     help="execution-policy impl map (see serve.py docstring)")
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO, format="%(message)s")
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
 
     if args.impl:
         from repro.kernels import policy
@@ -452,20 +482,19 @@ def main():
 
     import os
 
-    import jax
     import numpy as np
 
     from repro.configs import get_config, get_smoke_config
-    from repro.launch.mesh import make_debug_mesh
+    from repro.launch.mesh import replica_meshes
     from repro.models.base import RunOptions
 
     cfg = get_smoke_config(args.arch) if args.reduced else get_config(args.arch)
-    mesh = make_debug_mesh(tp=min(2, len(jax.devices())))
+    meshes = replica_meshes(args.replicas)
     plan = args.inject or os.environ.get("REPRO_FAULTS", "")
     engine_kw = dict(max_batch=args.slots, max_len=128, chunk=args.chunk,
                      snapshot_every=args.snapshot_every,
                      evict_policy=args.evict_policy, opts=RunOptions())
-    router = Router(cfg, mesh, n_replicas=args.replicas, route=args.route,
+    router = Router(cfg, meshes, n_replicas=args.replicas, route=args.route,
                     seed=args.seed, fleet_faults=plan,
                     queue_depth=args.queue_depth,
                     rebalance_threshold=args.rebalance_threshold or None,
@@ -495,7 +524,8 @@ def main():
         assert out["counters"]["migrations"] >= 1, \
             "expected at least one cross-replica snapshot-resume migration"
     if args.check_single:
-        single = Engine(cfg, mesh, injector=FaultInjector(""), **engine_kw)
+        single = Engine(cfg, meshes[0], injector=FaultInjector(""),
+                        **engine_kw)
         single.params = router.replicas[0].engine.params
         alone = [Request(i, p, max_new=mn) for i, (p, mn) in enumerate(spec)]
         single.run(alone)

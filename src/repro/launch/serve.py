@@ -7,8 +7,8 @@ a row that hits EOS / ``max_new`` stops appending (its lane still rides the
 batch until the wave's slowest request ends — that burned work is exactly
 what ``repro.launch.engine`` removes with per-row KV lengths, chunked
 prefill, and PWS slot scheduling; this module stays as the simple baseline
-and the parity oracle).  The KV cache is planned by the PWS planner
-(kv-heads over tp when divisible, else sequence-sharded).
+and the parity oracle).  Parameters are placed by the PWS planner
+(``core.planner.plan_params``) on the server's mesh.
 
 Backend selection is the ambient ``repro.kernels.policy`` execution
 policy's call.  The ``--impl`` flag installs a process policy with the
@@ -19,14 +19,16 @@ grammar
     --impl pallas                      bare backend == '*=backend'
     --impl op=backend:knob=value       variant knobs, e.g.
                                        --impl 'attention=pallas:kv_dtype=int8'
-                                       --impl 'matmul=pallas:backend=classical'
+                                       --impl 'matmul=pallas:qkv_fused=true'
+                                       --impl 'matmul=pallas:backend=strassen'
 
 where op is a registered kernel name (``scan`` | ``matmul`` | ``transpose``
 | ``attention`` | ``fft``) or ``*``, and backend one of ``auto`` (registry
 decides) | ``jnp`` | ``pallas``.  ``:knob=value`` suffixes set per-op
 variant knobs on the policy (``attention kv_dtype=int8`` selects the
-quantized KV cache; ``matmul backend=...``/``qkv_fused=true`` pin the
-matmul schedule / fused projections).  Under a pallas attention policy,
+quantized KV cache; ``matmul qkv_fused=true`` fuses the QKV projections;
+model matmuls always run the classical schedule, which keeps each token
+row independent of its batch).  Under a pallas attention policy,
 prefill
 dispatches as zero-offset self-attention and decode as a cached-attention
 call where the step position flows into the kernel as a traced ``q_offset``
@@ -107,8 +109,16 @@ class Server:
         self.model = build_model(cfg, self.opts)
         self.rules = default_rules(mesh)
 
+        # params land where the PWS planner puts them on this mesh (as a
+        # checkpoint-streamed restart places them), committed, so launches
+        # compile against them in place
+        key = jax.random.key(0)
+        shardings = planner.named(
+            planner.plan_params(jax.eval_shape(self.model.init, key), mesh),
+            mesh)
         with mesh, axis_rules(self.rules, mesh):
-            self.params = jax.jit(self.model.init)(jax.random.key(0))
+            self.params = jax.jit(self.model.init,
+                                  out_shardings=shardings)(key)
 
         def prefill(params, batch):
             return self.model.prefill(params, batch, max_len)
@@ -116,7 +126,7 @@ class Server:
         def decode(params, tokens, pos, cache):
             logits, cache = self.model.decode_step(params, tokens, pos, cache)
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return nxt, cache
+            return nxt, logits, cache
 
         self._prefill = jax.jit(prefill)
         self._decode = jax.jit(decode, donate_argnums=(3,))
@@ -161,7 +171,8 @@ class Server:
                 if all(done):
                     break  # the wave drained early — skip the dead steps
                 pos = jnp.asarray(plen + step, jnp.int32)
-                nxt, cache = self._decode(self.params, nxt[:, None], pos, cache)
+                nxt, _, cache = self._decode(self.params, nxt[:, None], pos,
+                                             cache)
         dt = time.time() - t0
         n_tokens = sum(len(r.out) for r in requests)
         return {"wall_s": dt, "tokens": n_tokens,
@@ -182,6 +193,8 @@ def main():
                          "--attention-impl/--matmul-impl (see module "
                          "docstring for the grammar)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
 
     if args.impl:
         from repro.kernels import policy

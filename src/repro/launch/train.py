@@ -128,6 +128,8 @@ def train(cfg, *, mesh, steps: int, data_cfg: DataConfig,
 
 def main():
     logging.basicConfig(level=logging.INFO, format="%(message)s")
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b")
     ap.add_argument("--steps", type=int, default=20)

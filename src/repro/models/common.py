@@ -436,17 +436,12 @@ def _attention_via_kernel(q, k, v, q_pos, k_pos, *, causal, window, q_block,
     past ``kv_len`` are skipped instead of computed-then-masked.
     ``k_scale``/``v_scale`` — per (batch, kv_head) f32, paired with an int8
     k/v — ride to the kernel's in-block dequant."""
+    from repro.core.sharding_hints import kernel_map, mesh_axis
     from repro.kernels import registry
 
     b, sq, h, hd = q.shape
     sk = k.shape[1]
     kvh = k.shape[2]
-
-    def fold(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], x.shape[1], hd)
-
-    def fold_scale(s):
-        return None if s is None else jnp.asarray(s, jnp.float32).reshape(b * kvh)
 
     if kv_len is not None:
         # explicit valid-key counts: keys are raw cache slots (base 0), so
@@ -471,19 +466,57 @@ def _attention_via_kernel(q, k, v, q_pos, k_pos, *, causal, window, q_block,
     # forward overrides only when divisor-exact; else the per-shape plan wins
     qb = q_block if (q_block and sq % min(q_block, sq) == 0) else None
     kb = kv_block if (kv_block and sk % min(kv_block, sk) == 0) else None
-    kwargs = {}
-    if kvh != h:
-        kwargs["n_heads"] = h
-    if k_scale is not None:
-        kwargs["k_scale"] = fold_scale(k_scale)
-        kwargs["v_scale"] = fold_scale(v_scale)
-    out = registry.dispatch(
-        "attention", fold(q), fold(k), fold(v), causal=causal,
-        window=0 if window is None else int(window),
-        q_offset=q_offset, kv_len=kv_len, impl="pallas",
-        q_block=qb, kv_block=kb, **kwargs,
-    )
-    return out.reshape(b, h, sq, hd).transpose(0, 2, 1, 3)
+    quantized = k_scale is not None
+
+    def kernel(q, k, v, *rest):
+        # runs on one shard's block: batch rows and head groups are
+        # independent, so any split along them is exact
+        lb, lh, lkvh = q.shape[0], q.shape[2], k.shape[2]
+        rest = list(rest)
+        qo = rest.pop(0) if q_offset is not None else None
+        kl = rest.pop(0) if kv_len is not None else None
+        kwargs = {}
+        if lkvh != lh:
+            kwargs["n_heads"] = lh
+        if quantized:
+            kwargs["k_scale"] = rest[0].reshape(lb * lkvh)
+            kwargs["v_scale"] = rest[1].reshape(lb * lkvh)
+
+        def fold(x):
+            return x.transpose(0, 2, 1, 3).reshape(lb * x.shape[2],
+                                                   x.shape[1], hd)
+
+        out = registry.dispatch(
+            "attention", fold(q), fold(k), fold(v), causal=causal,
+            window=0 if window is None else int(window),
+            q_offset=qo, kv_len=kl, impl="pallas",
+            q_block=qb, kv_block=kb, **kwargs,
+        )
+        return out.reshape(lb, lh, sq, hd).transpose(0, 2, 1, 3)
+
+    # heads split with their kv group only when both divide the same way
+    # (a query head's group must live on its shard); else both replicate
+    heads = "heads" if (mesh_axis("heads", h) is not None
+                        and mesh_axis("kv_heads", kvh) is not None) else None
+    kv_heads = "kv_heads" if heads else None
+    args = [q, k, v]
+    in_axes = [("batch", None, heads, None),
+               ("batch", None, kv_heads, None),
+               ("batch", None, kv_heads, None)]
+    for vec in (q_offset, kv_len):
+        if vec is not None:
+            vec = jnp.asarray(vec, jnp.int32)
+            args.append(vec)
+            # a per-row (b,) vector splits with the batch; a shared one
+            # (scalar or length 1) goes to every shard
+            in_axes.append(("batch",) if vec.shape == (b,) and b > 1
+                           else (None,) * vec.ndim)
+    if quantized:
+        for sc in (k_scale, v_scale):
+            args.append(jnp.asarray(sc, jnp.float32).reshape(b, kvh))
+            in_axes.append(("batch", kv_heads))
+    return kernel_map(kernel, args, in_axes, ("batch", None, heads, None),
+                      q.shape)
 
 
 def attention(q, k, v, q_pos, k_pos, *, causal=True, window=None, softmax_scale=None,
@@ -594,17 +627,37 @@ def quantize_kv(x, scale):
 def project(x, w):
     """x: (..., d) @ w: (d, f) -> (..., f), backend resolved by the ambient
     execution policy.  The pallas route folds the leading dims and
-    dispatches the registry's matmul — planner-tiled, backend-selected
-    (classical/Strassen by the costmodel envelopes), autotune-overlaid,
-    differentiable via the kernel's custom VJP; jnp keeps the XLA einsum."""
+    dispatches the registry's matmul (:func:`_matmul_kernel`: planner-tiled,
+    classical schedule, autotune-overlaid, differentiable via the kernel's
+    custom VJP); jnp keeps the XLA einsum."""
     from repro.kernels import registry
 
     if registry.resolve("matmul") == "pallas":
         lead = x.shape[:-1]
-        out = registry.dispatch("matmul", x.reshape(-1, x.shape[-1]), w,
-                                impl="pallas")
+        out = _matmul_kernel(x.reshape(-1, x.shape[-1]), w)
         return out.reshape(*lead, w.shape[-1])
     return jnp.einsum("...d,df->...f", x, w)
+
+
+def _matmul_kernel(a, b, out_axis: str = "ffn"):
+    """The registry's matmul kernel on (m, k) @ (k, n), once per shard of a
+    bound mesh (``kernel_map``): token rows split over the batch axes and
+    output columns over the ``out_axis`` binding — only where each shard's
+    columns stay lane-aligned (the kernel tiles them by 128) — the
+    contraction whole.
+
+    The schedule is the planner's: classical, so each token row's result
+    depends on that row alone, whatever requests share the launch, unless
+    the policy asks for Strassen (``planner.plan_matmul``)."""
+    from repro.core.sharding_hints import kernel_map, mesh_axis
+    from repro.kernels import registry
+
+    if mesh_axis(out_axis, b.shape[1], multiple=128) is None:
+        out_axis = None
+    return kernel_map(
+        lambda x, y: registry.dispatch("matmul", x, y, impl="pallas"),
+        (a, b), (("batch", None), (None, out_axis)), ("batch", out_axis),
+        (a.shape[0], b.shape[1]))
 
 
 def qkv_project(x, wq, wk, wv):
@@ -639,8 +692,7 @@ def attn_out_project(o, wo):
     from repro.kernels import registry
 
     if registry.resolve("matmul") == "pallas":
-        out = registry.dispatch("matmul", o.reshape(b * s, h * hd),
-                                wo.reshape(h * hd, -1), impl="pallas")
+        out = _matmul_kernel(o.reshape(b * s, h * hd), wo.reshape(h * hd, -1))
         return out.reshape(b, s, -1)
     return jnp.einsum("bshd,hdf->bsf", o, wo.reshape(h, hd, -1))
 
@@ -674,13 +726,13 @@ def gated_mlp(x, w_gate, w_up, w_down):
 def logits_matmul(h, embed_out):
     """Output-logits product h @ embed_outᵀ in fp32.  h: (..., d),
     embed_out: (V, d) -> (..., V).  The hottest serve-path matmul: the
-    pallas route dispatches the registry's backend-selected kernel."""
+    pallas route dispatches the registry's kernel (:func:`_matmul_kernel`)."""
     from repro.kernels import registry
 
     if registry.resolve("matmul") == "pallas":
         lead = h.shape[:-1]
-        out = registry.dispatch("matmul", h.reshape(-1, h.shape[-1]),
-                                embed_out.T, impl="pallas")
+        out = _matmul_kernel(h.reshape(-1, h.shape[-1]), embed_out.T,
+                             out_axis="vocab")
         return out.reshape(*lead, embed_out.shape[0]).astype(jnp.float32)
     return jnp.einsum("...d,vd->...v", h, embed_out).astype(jnp.float32)
 

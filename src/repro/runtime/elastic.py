@@ -51,16 +51,17 @@ def replan_params_for_mesh(abstract_params: Any, new_mesh):
 
 def respawn_mesh(prev_mesh, lost_devices: int = 0):
     """The mesh a replacement replica spins up on after its predecessor
-    dies: the same device count minus ``lost_devices`` (a dead replica's
-    hosts may be gone for good), re-planned through the debug-mesh
-    factory so tensor-parallel stays as wide as the survivors allow.
-    Shrinking to fewer devices is always legal — the PWS planner is
+    dies: the predecessor's own devices minus ``lost_devices`` (a dead
+    replica's hosts may be gone for good), re-planned through the
+    debug-mesh factory so tensor-parallel stays as wide as the survivors
+    allow.  Shrinking to fewer devices is always legal — the PWS planner is
     deterministic in the mesh, so the respawned replica's logits match the
     original's whatever the shape (asserted by the router tests)."""
     from repro.launch.mesh import make_debug_mesh, mesh_device_count
 
     n = max(mesh_device_count(prev_mesh) - int(lost_devices), 1)
-    return make_debug_mesh(n, tp=min(2, n))
+    return make_debug_mesh(n, tp=min(2, n),
+                           devices=list(prev_mesh.devices.flat))
 
 
 def serving_restore(ckpt_manager, abstract_params: Any, new_mesh):

@@ -31,6 +31,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
+import jax
 import numpy as np
 
 log = logging.getLogger(__name__)
@@ -76,6 +77,13 @@ def parse_fleet_plan(plan: str, n_replicas: int) -> list[str]:
 class InjectedFault(RuntimeError):
     """A fault raised by :class:`FaultInjector` (distinguishable from real
     failures in logs; handled identically by the retry machinery)."""
+
+
+# What a serving launch may retry: a fault the injector fired, or an error
+# the device runtime reports while the launch executes.  A program error (a
+# ValueError while tracing, a refused compile) is not in this tuple, so it
+# propagates on the first attempt.
+RETRYABLE_FAULTS = (InjectedFault, jax.errors.JaxRuntimeError)
 
 
 class LaunchFailedError(RuntimeError):

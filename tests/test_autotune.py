@@ -326,6 +326,45 @@ def test_device_params_queries_memory_stats(monkeypatch, stats, want):
     assert dp.kind == "fake-l2"
 
 
+class _FakeTPU(_FakeDev):
+    platform = "tpu"
+
+    def __init__(self, kind):
+        super().__init__({"bytes_limit": 16 * 2**30})  # HBM, not VMEM
+        self.device_kind = kind
+
+
+def test_device_params_tpu_reads_the_kind_table(monkeypatch):
+    """A TPU's fast memory comes from the ``device_kind`` table (the
+    scoped-VMEM limit), never from memory_stats()'s HBM-sized limit; a kind
+    the table lacks is an error, not a default."""
+    monkeypatch.delenv("REPRO_FAST_BYTES", raising=False)
+    dp = planner.device_params(_FakeTPU("TPU v5 lite"))
+    assert dp.fast_bytes == planner.TPU_VMEM_BYTES["TPU v5 lite"] == 16 * 2**20
+    with pytest.raises(ValueError, match="TPU kind"):
+        planner.device_params(_FakeTPU("TPU v99"))
+
+
+@pytest.mark.parametrize("m", [998, 1021, 8])
+def test_tpu_matmul_plan_takes_whole_axis_over_misaligned_tile(m):
+    """On a TPU every block dim is a multiple of its tiling or the whole
+    axis: a ragged row count (a prompt's token count) with no aligned
+    divisor under the cap plans the whole axis, within fast memory."""
+    dp = planner.DeviceParams("tpu", "TPU v5 lite", 16 * 2**20, 512)
+    plan = planner.plan_matmul(m, 2048, 2048, jnp.bfloat16, dp)
+    assert plan["bm"] == m or plan["bm"] % dp.sublane(jnp.bfloat16) == 0
+    assert plan["bm"] == m  # 998 = 2*499, 1021 prime, 8 < one bf16 tile
+    assert planner.matmul_working_set(plan["bm"], plan["bn"], plan["bk"],
+                                      2) <= dp.fast_bytes
+
+
+def test_plan_raises_when_no_legal_tile_fits():
+    """A plan never quietly exceeds the fast memory it is given."""
+    dp = planner.DeviceParams("tpu", "tiny", 64 * 2**10, 512)
+    with pytest.raises(ValueError, match="fast memory"):
+        planner.plan_matmul(998, 2048, 2048, jnp.bfloat16, dp)
+
+
 def test_ref_path_warns_once_on_dropped_tile_overrides(monkeypatch):
     monkeypatch.setattr(registry, "_WARNED_DROPPED", set())
     x = jax.random.normal(jax.random.key(0), (2, 256))

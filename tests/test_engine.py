@@ -142,11 +142,25 @@ def test_engine_unsupported_family_is_structured(mesh, monkeypatch):
 
 def test_engine_decode_compiles_once(mesh):
     """The no-recompile acceptance check: per-row positions are traced
-    vectors, so one compilation of the batched decode step serves every
-    ragged composition of slot depths the run produces."""
-    engine, _, out = _run_and_check(mesh, chunk=8)
+    vectors, so the one decode executable compiled ahead of serving serves
+    every ragged composition of slot depths the run produces — and a
+    launch at any other shape is refused at once, never recompiled and
+    never retried as a fault."""
+    cfg = get_smoke_config("qwen3-1.7b")
+    engine = Engine(cfg, mesh, max_batch=3, max_len=64, chunk=8,
+                    opts=RunOptions())
+    engine.compile()
+    programs = dict(engine._programs)
+    reqs = _requests(6, vocab=cfg.vocab_size)
+    out = engine.run(reqs)
     assert out["decode_steps"] > 1
-    assert engine.stats()["decode_compilations"] == 1
+    assert engine._programs == programs
+    assert all(engine._programs[k] is p for k, p in programs.items())
+    params, tokens, pos, cache, poison = engine._launch_args("decode")
+    with pytest.raises(TypeError, match="compiled"):
+        engine._launch("decode", "decode", params, tokens[:2], pos[:2],
+                       cache, poison[:2])
+    assert engine.stats()["faults"]["retries"] == 0
 
 
 # -- eviction policy + structured stats ---------------------------------------
@@ -190,7 +204,6 @@ def test_engine_stats_structure(mesh):
     assert set(FAULT_COUNTER_KEYS) <= set(stats["faults"])
     assert not set(FAULT_COUNTER_KEYS) & set(stats["scheduler"])
     assert stats["launches"]["decode"] > 0 and stats["busy_s"] > 0
-    assert stats["decode_compilations"] == 1
     deg = stats["degradation"]
     assert deg["active_limit"] == deg["max_batch"] == engine.max_batch
 

@@ -195,6 +195,26 @@ def test_engine_launch_exhaustion_raises(served):
         engine.fault_policy = old
 
 
+def test_engine_program_error_raises_at_once(mesh, monkeypatch):
+    """A program error — here a ValueError raised while the decode step is
+    traced — is not a fault: it propagates on the first attempt, with no
+    retry and no LaunchFailedError."""
+    cfg = get_smoke_config("qwen3-1.7b")
+    engine = Engine(cfg, mesh, max_batch=2, max_len=32, chunk=8,
+                    injector=FaultInjector(""), opts=RunOptions())
+    calls = []
+
+    def broken(*args, **kwargs):
+        calls.append(1)
+        raise ValueError("broken decode program")
+
+    monkeypatch.setattr(engine.model, "decode_step", broken)
+    with pytest.raises(ValueError, match="broken decode program"):
+        engine.run([Request(0, np.arange(3, 9, dtype=np.int32), max_new=4)])
+    assert len(calls) == 1
+    assert engine.scheduler.counters["retries"] == 0
+
+
 def test_engine_poisoned_slot_bisected_and_restored(served):
     """Failure model (b): one slot's logits go non-finite; the per-row
     validity vector bisects it, ONLY that request is re-queued, it resumes

@@ -288,6 +288,32 @@ def test_strict_tiles_policy(monkeypatch):
         registry.dispatch("scan", x, impl="ref", block=64)  # back to warning
 
 
+@pytest.mark.parametrize("case", ["matmul_snap", "attention_snap",
+                                  "needs_gate"])
+def test_strict_tiles_forbid_silent_fallbacks(monkeypatch, case):
+    """Under strict tiles no call quietly leaves its kernel: a degenerate
+    tile snap in ``hbp_matmul`` (prime dim, sub-sublane tile) or in flash
+    attention, and the registry's ``needs`` gate, raise instead of running
+    ``jnp.dot`` or the oracle."""
+    monkeypatch.delenv("REPRO_STRICT_TILES", raising=False)
+    key = jax.random.key(0)
+    with policy.apply(strict_tiles=True):
+        with pytest.raises(ValueError, match="strict tiles"):
+            if case == "matmul_snap":
+                a = jax.random.normal(key, (31, 31))
+                registry.dispatch("matmul", a, a, impl="pallas",
+                                  bm=16, bn=16, bk=16)
+            elif case == "attention_snap":
+                q = jax.random.normal(key, (2, 127, 32))
+                registry.dispatch("attention", q, q, q, impl="pallas",
+                                  causal=True, q_block=64, kv_block=64)
+            else:
+                q = jax.random.normal(key, (2, 8, 32))
+                with policy.apply(impl={"attention": "pallas"}):
+                    registry.dispatch("attention", q, q, q, causal=True,
+                                      softmax_scale=0.5)
+
+
 def test_reset_warnings_rearms_warn_once():
     x = jax.random.normal(jax.random.key(0), (2, 256))
     with pytest.warns(UserWarning, match="ignored on the"):

@@ -10,6 +10,7 @@ from hypothesis import strategies as st
 
 from repro.core import layouts, planner
 from repro.launch.hlo_analysis import shape_bytes
+from repro.launch.mesh import make_mesh
 from repro.models.moe_layer import SUBLANE, gapped_capacity
 
 
@@ -19,7 +20,7 @@ from repro.models.moe_layer import SUBLANE, gapped_capacity
        st.sampled_from(["wq", "wo", "embed", "e_gate", "ln1", "unknown_leaf"]))
 @settings(max_examples=40, deadline=None)
 def test_planner_never_emits_indivisible_specs(dim_pows, name):
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = tuple(2 ** p for p in dim_pows)
     tree = {name: jax.ShapeDtypeStruct(shape, jnp.float32)}
     specs = planner.plan_params(tree, mesh)

@@ -9,6 +9,7 @@ failures perturb *placement*, never tokens.  The ``rws`` two-choice core is
 unit-tested without a fleet.
 """
 import random
+import time
 
 import jax
 import numpy as np
@@ -157,6 +158,37 @@ def test_router_replica_death_respawns_token_identical(mesh, cfg):
                and r.state == "live" for r in router.replicas)
     assert all(len(r.out) == r.max_new for r in reqs)
     _assert_single_replica_parity(router, spec, reqs)
+
+
+def test_router_stops_on_program_error(mesh, cfg, monkeypatch):
+    """A program error on a replica is not a death: the router respawns
+    nothing and the error propagates out of ``run`` at once."""
+    router = Router(cfg, mesh, n_replicas=2, route="pws", **_kw())
+
+    def broken(*args, **kwargs):
+        raise ValueError("broken decode program")
+
+    for rep in router.replicas:
+        monkeypatch.setattr(rep.engine.model, "decode_step", broken)
+    t0 = time.monotonic()
+    with pytest.raises(ValueError, match="broken decode program"):
+        router.run(_reqs(_spec(cfg, n=4)))
+    assert time.monotonic() - t0 < 120
+    assert router.counters["replica_deaths"] == 0
+    assert router.counters["replica_restarts"] == 0
+    assert len(router.replicas) == 2
+
+
+def test_router_restart_budget_stops_the_run(mesh, cfg):
+    """A death past the ``max_restarts`` budget stops the run with an
+    error instead of respawning forever."""
+    router = Router(cfg, mesh, n_replicas=2, route="pws",
+                    fleet_faults="|decode@0=raise:99", **_kw())
+    router.max_restarts = 0
+    with pytest.raises(RuntimeError, match="restart budget"):
+        router.run(_reqs(_spec(cfg, n=4)))
+    assert router.counters["replica_deaths"] == 1
+    assert router.counters["replica_restarts"] == 0
 
 
 # -- in-flight rebalancing ----------------------------------------------------

@@ -1,7 +1,7 @@
 """Strassen-schedule matmul: kernel numerics vs the oracle across
-dtypes/sizes (incl. shapes that must route classical), planner backend
-selection at the costmodel crossover (with a hypothesis monotonicity
-property), v3 backend-flagged autotune keys (search/replay round-trip,
+dtypes/sizes (incl. shapes that must route classical), the planner's gate
+on a Strassen request at the costmodel crossover (with a hypothesis
+monotonicity property; unrequested, every plan is classical), v3 backend-flagged autotune keys (search/replay round-trip,
 variant candidates, cross-shape interpolation), ragged hbp_matmul
 overrides, and model-matmul routing parity (greedy decode + one train
 step, impl="pallas" vs impl="jnp")."""
@@ -83,8 +83,8 @@ def test_strassen_ineligible_shape_falls_through():
 
 
 def test_backend_matmul_dispatch_and_vjp():
-    """The registry's matmul entry: explicit backend override, planner
-    default, and gradients through both backends match the jnp grads."""
+    """The registry's matmul entry: explicit backend override, and
+    gradients through both backends match the jnp grads."""
     a, b = _mats(128, jnp.float32)
     for backend in ("classical", "strassen"):
         got = registry.dispatch("matmul", a, b, impl="pallas",
@@ -106,30 +106,34 @@ def test_backend_matmul_dispatch_and_vjp():
 # -- planner backend selection ------------------------------------------------
 
 def test_plan_matmul_backend_crossover():
-    """Strassen only above the modeled crossover, only for square
-    pow2-friendly edges, only for fp32/bf16."""
+    """A Strassen request recurses only above the modeled crossover, only
+    for square pow2-friendly edges, only for fp32/bf16; the tile plan
+    itself never picks Strassen."""
     cut = planner.strassen_cutoff(jnp.float32, DP)
     assert cut == costmodel.strassen_crossover_edge(
         DP.fast_bytes // 3 // 4, DP.line_bytes // 4)
-    below = planner.plan_matmul(cut, cut, cut, jnp.float32, DP)
-    above = planner.plan_matmul(2 * cut, 2 * cut, 2 * cut, jnp.float32, DP)
+    below = planner.plan_matmul_backend(cut, cut, cut, jnp.float32, DP)
+    above = planner.plan_matmul_backend(2 * cut, 2 * cut, 2 * cut,
+                                        jnp.float32, DP)
     assert below["backend"] == "classical" and "cutoff" not in below
     assert above["backend"] == "strassen" and above["cutoff"] == cut
+    assert "backend" not in planner.plan_matmul(2 * cut, 2 * cut, 2 * cut,
+                                                jnp.float32, DP)
     # non-square / low-precision / odd-above-cutoff shapes stay classical
-    assert planner.plan_matmul(2 * cut, cut, 2 * cut, jnp.float32,
-                               DP)["backend"] == "classical"
-    assert planner.plan_matmul(2 * cut, 2 * cut, 2 * cut, jnp.int8,
-                               DP)["backend"] == "classical"
+    assert planner.plan_matmul_backend(2 * cut, cut, 2 * cut, jnp.float32,
+                                       DP)["backend"] == "classical"
+    assert planner.plan_matmul_backend(2 * cut, 2 * cut, 2 * cut, jnp.int8,
+                                       DP)["backend"] == "classical"
     odd = 2 * (cut + 1)  # halves once to an odd edge just above the cutoff
     assert odd % 2 == 0 and (odd // 2) % 2 and odd // 2 > cut
-    assert planner.plan_matmul(odd, odd, odd, jnp.float32,
-                               DP)["backend"] == "classical"
+    assert planner.plan_matmul_backend(odd, odd, odd, jnp.float32,
+                                       DP)["backend"] == "classical"
 
 
 def test_plan_matmul_backend_monotone_in_n():
-    """Hypothesis property: over square power-of-two n, once the planner
-    picks Strassen it keeps picking it for every larger n (at any queried
-    fast-memory size and eligible dtype)."""
+    """Hypothesis property: over square power-of-two n, once the planner's
+    gate admits a Strassen request it keeps admitting it for every larger n
+    (at any queried fast-memory size and eligible dtype)."""
     pytest.importorskip("hypothesis")  # optional dep: skip cleanly when absent
     from hypothesis import given, settings
     from hypothesis import strategies as st
@@ -140,7 +144,7 @@ def test_plan_matmul_backend_monotone_in_n():
     @settings(max_examples=40, deadline=None)
     def check(dtype, mem_pow, line):
         dp = planner.DeviceParams("cpu", "prop", 2 ** mem_pow, line)
-        picks = [planner.plan_matmul(n, n, n, dtype, dp)["backend"]
+        picks = [planner.plan_matmul_backend(n, n, n, dtype, dp)["backend"]
                  for n in (1 << j for j in range(5, 15))]
         first = picks.index("strassen") if "strassen" in picks else len(picks)
         assert all(p == "classical" for p in picks[:first])
@@ -154,8 +158,8 @@ def test_plan_matmul_backend_monotone_in_n():
 def test_entry_key_carries_matmul_backend_flag():
     a = jax.ShapeDtypeStruct((512, 512), jnp.float32)
     key = autotune.entry_key("matmul", a, a)
-    assert "backend=" in key
-    # an explicit kwarg overrides the planner-derived flag
+    assert "backend=classical" in key
+    # a requested backend keys its own entry
     forced = autotune.entry_key("matmul", a, a, kwargs={"backend": "strassen"})
     assert "backend=strassen" in forced
 
@@ -165,7 +169,14 @@ def test_matmul_candidates_cover_backend_and_morton():
     cands = autotune.candidates("matmul", a, a, dp=DP)
     assert cands[0] == dict(registry.get("matmul").plan(a, a))
     assert any(p.get("morton") is False for p in cands)
-    assert any(p.get("backend") == "strassen" for p in cands)
+    # a classical request never tries the row-mixing Strassen schedule
+    assert not any(p.get("backend") == "strassen" for p in cands)
+    # a Strassen request walks its cutoff and offers classical
+    asked = autotune.candidates("matmul", a, a, dp=DP,
+                                variant={"backend": "strassen"})
+    assert asked[0]["backend"] == "strassen"
+    assert any(p.get("cutoff") == 256 for p in asked)
+    assert any(p.get("backend") == "classical" for p in asked)
     # transpose tunes its morton flag too
     x = jax.ShapeDtypeStruct((256, 256), jnp.float32)
     assert any(p.get("morton") is False
@@ -174,22 +185,24 @@ def test_matmul_candidates_cover_backend_and_morton():
 
 def test_search_replay_roundtrip_with_backend_keys(tune_dir, monkeypatch):
     """Shrink the queried fast memory so a 256-edge matmul crosses into the
-    Strassen regime, search it, and replay the (backend-flagged) winner
-    through dispatch."""
+    Strassen regime, search it under a Strassen request, and replay the
+    (backend-flagged) winner through dispatch."""
     monkeypatch.setenv("REPRO_FAST_BYTES", str(1 << 18))
     planner.clear_device_params_cache()
     try:
-        plan = planner.plan_matmul(256, 256, 256, jnp.float32)
+        plan = planner.plan_matmul_backend(256, 256, 256, jnp.float32)
         assert plan["backend"] == "strassen"
         a, b = _mats(256, jnp.float32)
-        entry = autotune.search("matmul", a, b, iters=1, max_candidates=4)
+        asked = {"backend": "strassen"}
+        entry = autotune.search("matmul", a, b, iters=1, max_candidates=4,
+                                **asked)
         assert entry["plan"].get("backend") in ("classical", "strassen")
-        key = autotune.entry_key("matmul", a, b)
+        key = autotune.entry_key("matmul", a, b, kwargs=asked)
         assert "backend=strassen" in key
         autotune.clear_cache()  # force the JSON round-trip
-        assert autotune.lookup("matmul", a, b) == entry["plan"]
+        assert autotune.lookup("matmul", a, b, kwargs=asked) == entry["plan"]
         with autotune.mode_scope("replay"):
-            got = registry.dispatch("matmul", a, b, impl="pallas")
+            got = registry.dispatch("matmul", a, b, impl="pallas", **asked)
         np.testing.assert_allclose(np.asarray(got), np.asarray(a @ b),
                                    rtol=2e-3, atol=2e-3)
     finally:
@@ -314,3 +327,31 @@ def test_model_matmul_impl_train_step_parity():
     for a, b in zip(jax.tree.leaves(gj), jax.tree.leaves(gp)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=5e-3, atol=1e-5)
+
+
+def test_model_projection_is_batch_invariant(monkeypatch):
+    """A model projection's row i depends on row i alone, whatever shares
+    its batch, even for a square product where a Strassen request would
+    recurse: the schedule is classical unless the policy asks, since
+    Strassen adds row i to row i + m/2 in its quadrant sums.  (On the chip
+    a prefill chunk of 8 slots x 256 tokens is exactly d_model = 2048
+    rows.)  Asked for, Strassen does mix the rows."""
+    from repro.kernels import policy
+    from repro.models import common
+
+    monkeypatch.setenv("REPRO_FAST_BYTES", str(1 << 18))  # Strassen above 128
+    planner.clear_device_params_cache()
+    assert planner.plan_matmul_backend(256, 256, 256,
+                                       jnp.bfloat16)["backend"] == "strassen"
+    x, w = _mats(256, jnp.bfloat16)
+    other = x.at[128:].set(-x[128:])  # same first half, different second
+    with policy.apply(impl={"matmul": "pallas"}):
+        a = common.project(x, w)
+        b = common.project(other, w)
+    with policy.apply(impl={"matmul": "pallas"},
+                      variants={"matmul": {"backend": "strassen"}}):
+        sa = common.project(x, w)
+        sb = common.project(other, w)
+    planner.clear_device_params_cache()
+    np.testing.assert_array_equal(np.asarray(a[:128]), np.asarray(b[:128]))
+    assert not np.array_equal(np.asarray(sa[:128]), np.asarray(sb[:128]))

@@ -16,6 +16,7 @@ from hypothesis import strategies as st
 from repro.checkpoint import CheckpointManager, load_checkpoint, save_checkpoint
 from repro.configs import get_smoke_config
 from repro.data import DataConfig, SyntheticLMDataset
+from repro.launch.mesh import make_mesh
 from repro.optim import (
     AdamWConfig,
     adamw_init,
@@ -207,7 +208,7 @@ def test_elastic_reshard_roundtrip(tmp_path):
 
     state = _state()
     save_checkpoint(tmp_path, 2, state, mesh_shape={"data": 1, "model": 1})
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     plan = replan_for_mesh(
         {"params": state["params"],
          "opt_state": {"step": state["opt_state"]["step"],

@@ -11,7 +11,7 @@ import pytest
 from repro.configs import SHAPES, get_smoke_config
 from repro.core import planner
 from repro.data import DataConfig
-from repro.launch.mesh import make_debug_mesh
+from repro.launch.mesh import make_debug_mesh, make_mesh
 from repro.launch.train import train
 from repro.models.base import RunOptions
 
@@ -101,7 +101,7 @@ def test_planner_specs_divisible():
     from repro.models import build_model
 
     cfg = get_smoke_config("qwen3-moe-30b-a3b")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     model = build_model(cfg)
     ap = abstract_params(model)
     specs = planner.plan_params(ap, mesh)

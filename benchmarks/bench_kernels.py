@@ -400,116 +400,6 @@ def _bench_serve_faulted() -> dict:
     return entry
 
 
-def _bench_serve_router() -> dict:
-    """Fleet-scaling arm: the SAME skewed 12-request workload through a
-    1-replica and a 2-replica ``Router`` (pws arm, max_batch=2 per
-    replica).  The recorded ratio is fleet throughput against the MAKESPAN
-    clock ``max(busy_s)`` — on this one-device rig replicas time-share the
-    device, so per-replica busy time is the production-shape number (see
-    "Fleet clock" in the router docstring); the sequential wall is recorded
-    alongside for honesty.  Warmup = the full workload once per fleet;
-    best-of-3 timed runs; tokens asserted identical across fleet sizes.  A
-    faulted variant then kills one replica mid-decode and records the
-    recovery overhead of salvage + checkpoint-streamed respawn + snapshot
-    migration over the clean 2-replica makespan — with tokens again
-    identical and ``replica_restarts >= 1``."""
-    import numpy as np
-
-    from repro.configs import get_smoke_config
-    from repro.launch.mesh import make_debug_mesh
-    from repro.launch.router import Router
-    from repro.launch.serve import Request
-    from repro.models.base import RunOptions
-    from repro.runtime.fault_tolerance import FaultInjector
-
-    cfg = get_smoke_config("qwen3-1.7b")
-    mesh = make_debug_mesh(tp=1)
-    rng = np.random.default_rng(0)
-    # skewed: one long generation per wave of shorts, three waves
-    spec = [(rng.integers(3, cfg.vocab_size, 12).astype(np.int32), mn)
-            for _ in range(3) for mn in (24, 2, 2, 2)]
-
-    def requests():
-        return [Request(i, p, max_new=mn) for i, (p, mn) in enumerate(spec)]
-
-    # degrade_after pinned high: timing jitter on the shared device must not
-    # trip the watchdog into shrinking a fleet's active slots mid-trial
-    kw = dict(max_batch=2, max_len=64, chunk=16, snapshot_every=8,
-              degrade_after=10**9, opts=RunOptions())
-
-    def fleet(n):
-        router = Router(cfg, mesh, n_replicas=n, route="pws", **kw)
-        router.run(requests())          # warmup: compiles land untimed
-        best, toks = None, None
-        for _ in range(3):
-            reqs = requests()
-            out = router.run(reqs)
-            if best is None or out["fleet_busy_s"] < best["fleet_busy_s"]:
-                best = out
-            if toks is None:
-                toks = [r.out for r in reqs]
-            else:
-                assert [r.out for r in reqs] == toks, \
-                    "router tokens vary across timed trials"
-        return router, best, toks
-
-    _, one, toks1 = fleet(1)
-    router2, two, toks2 = fleet(2)
-    assert toks1 == toks2, "fleet size changed the tokens"
-    speedup = two["fleet_tok_per_s"] / max(one["fleet_tok_per_s"], 1e-9)
-    assert speedup >= 1.6, \
-        f"2-replica fleet speedup {speedup:.2f}x under the 1.6x bar"
-
-    # faulted variant on its own fleet: a snapshot cadence dense enough
-    # that rows killed at decode ordinal 4 carry host snapshots to migrate;
-    # the killing plan installs AFTER warmup + its own clean baseline run
-    del router2
-    frouter = Router(cfg, mesh, n_replicas=2, route="pws",
-                     **dict(kw, snapshot_every=2))
-    frouter.run(requests())             # warmup
-    clean2_reqs = requests()
-    clean2 = frouter.run(clean2_reqs)
-    frouter.replicas[1].engine.injector = FaultInjector("decode@4=raise:99")
-    faulted_reqs = requests()
-    faulted = frouter.run(faulted_reqs)
-    fc = faulted["counters"]
-    assert [r.out for r in faulted_reqs] == [r.out for r in clean2_reqs], \
-        "faulted-fleet tokens diverge from the clean run"
-    assert fc["replica_restarts"] >= 1 and fc["migrations"] >= 1
-
-    entry = {
-        "op": "serve", "shape": "router_12reqs_skewed", "route": "pws",
-        "replicas": 2, "slots_per_replica": 2,
-        "fleet_tok_per_s_1rep": round(one["fleet_tok_per_s"], 1),
-        "fleet_tok_per_s_2rep": round(two["fleet_tok_per_s"], 1),
-        "fleet_speedup_2rep": round(speedup, 2),
-        "seq_tok_per_s_2rep": round(two["tok_per_s"], 1),
-        "faulted": {
-            "plan": "|decode@4=raise:99",
-            "fleet_tok_per_s": round(faulted["fleet_tok_per_s"], 1),
-            "recovery_overhead": round(
-                faulted["fleet_busy_s"] / max(clean2["fleet_busy_s"], 1e-9),
-                2),
-            "replica_deaths": fc["replica_deaths"],
-            "replica_restarts": fc["replica_restarts"],
-            "requeued_on_death": fc["requeued_on_death"],
-            "migrations": fc["migrations"],
-        },
-    }
-    print(f"kernel_serve_router_1rep_{entry['shape']},"
-          f"{one['fleet_busy_s'] / max(one['tokens'], 1) * 1e6:.0f},"
-          f"{entry['fleet_tok_per_s_1rep']}tok/s")
-    print(f"kernel_serve_router_2rep_{entry['shape']},"
-          f"{two['fleet_busy_s'] / max(two['tokens'], 1) * 1e6:.0f},"
-          f"{entry['fleet_tok_per_s_2rep']}tok/s "
-          f"({entry['fleet_speedup_2rep']}x fleet, tokens identical)")
-    print(f"kernel_serve_router_faulted_{entry['shape']},"
-          f"{faulted['fleet_busy_s'] / max(faulted['tokens'], 1) * 1e6:.0f},"
-          f"{entry['faulted']['recovery_overhead']}x clean fleet "
-          f"({fc['replica_restarts']} respawn(s), tokens identical)")
-    return entry
-
-
 def main(json_path: str | None = None, ops: list[str] | None = None) -> dict:
     from repro.launch.compile_cache import setup_compile_cache
     setup_compile_cache()
@@ -570,8 +460,6 @@ def main(json_path: str | None = None, ops: list[str] | None = None) -> dict:
         results["serve_continuous_hybrid"] = _bench_serve_continuous_hybrid()
     if ops is None or "serve_faulted" in ops:
         results["serve_faulted"] = _bench_serve_faulted()
-    if ops is None or "serve_router" in ops:
-        results["serve_router"] = _bench_serve_router()
 
     from repro.kernels import policy
     dp = planner.device_params()

@@ -137,6 +137,7 @@ from repro.runtime.fault_tolerance import (
     StragglerMonitor,
     export_fault_counters,
 )
+from repro.runtime.spans import Spans
 
 log = logging.getLogger("repro.engine")
 
@@ -310,16 +311,29 @@ class Engine(Server):
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return nxt, cache
 
-        import functools
-        self._decode_rows = jax.jit(decode_rows, donate_argnums=(3,))
-        self._chunk_first = jax.jit(
-            functools.partial(chunk_step, first=True), donate_argnums=(4,))
-        self._chunk_cont = jax.jit(
-            functools.partial(chunk_step, first=False), donate_argnums=(4,))
+        def prefill_first(params, tokens, offset, lens, cache, extras):
+            return chunk_step(params, tokens, offset, lens, cache, extras,
+                              first=True)
+
+        def prefill_cont(params, tokens, offset, lens, cache, extras):
+            return chunk_step(params, tokens, offset, lens, cache, extras,
+                              first=False)
+
+        # launch name -> jitted function; each is a named function, so its
+        # program reads ``jit_decode_rows``, ``jit_prefill_first`` or
+        # ``jit_prefill_cont`` in a profile
+        self._jitted = {
+            "decode": jax.jit(decode_rows, donate_argnums=(3,)),
+            "prefill_first": jax.jit(prefill_first, donate_argnums=(4,)),
+            "prefill_cont": jax.jit(prefill_cont, donate_argnums=(4,)),
+        }
         # each launch compiles ONCE for the engine's fixed shapes, outside
         # the failure model (see :meth:`_program`); name -> executable
         self._programs: dict = {}
         self.compile_s: dict[str, float] = {}
+        # host spans (``runtime.spans``): profiler annotations named
+        # ``engine.*`` and their per-run aggregate in ``stats()["spans"]``
+        self._spans = Spans()
         self.begin([])  # stats()/adopt() are valid before the first run
 
     @classmethod
@@ -368,9 +382,6 @@ class Engine(Server):
         self.scheduler.counters["evictions"] += 1
 
     # -- compiled launches ---------------------------------------------------
-    _LAUNCHES = {"decode": "_decode_rows", "prefill_first": "_chunk_first",
-                 "prefill_cont": "_chunk_cont"}
-
     def _program(self, name: str, args):
         """The compiled executable of launch ``name``, built on first use.
         Compiling happens here, outside :meth:`_launch`'s retry loop: a
@@ -382,9 +393,9 @@ class Engine(Server):
         never silently recompile."""
         prog = self._programs.get(name)
         if prog is None:
-            t0 = time.perf_counter()
-            prog = getattr(self, self._LAUNCHES[name]).lower(*args).compile()
-            self.compile_s[name] = time.perf_counter() - t0
+            with self._spans.span("engine.compile") as sp:
+                prog = self._jitted[name].lower(*args).compile()
+            self.compile_s[name] = sp.s
             self._programs[name] = prog
         return prog
 
@@ -407,7 +418,7 @@ class Engine(Server):
         """Compile all three launches ahead of serving (nothing runs).
         Returns the compile seconds per launch."""
         with self.mesh, axis_rules(self.rules, self.mesh):
-            for name in self._LAUNCHES:
+            for name in self._jitted:
                 self._program(name, self._launch_args(name))
         return dict(self.compile_s)
 
@@ -422,36 +433,37 @@ class Engine(Server):
         same arguments is sound because faults fire before the launch
         commits its donated buffers.  Exhausted retries escalate as
         :class:`LaunchFailedError` (failure model (a))."""
-        t_start = time.time()
-        prog = self._program(name, args)
-        ordinal = self._launch_seq[kind]
-        self._launch_seq[kind] = ordinal + 1
-        counters = self.scheduler.counters
-        last: Optional[BaseException] = None
-        for attempt in range(self.fault_policy.max_retries + 1):
-            if attempt:
-                counters["retries"] += 1
-                time.sleep(self.fault_policy.backoff(attempt - 1,
-                                                     self._fault_rng))
-            # the watchdog sees a launch's wall time as a jit call would
-            # show it: a first launch includes its compile
-            t0 = t_start if attempt == 0 else time.time()
-            try:
-                self.injector.before_launch(kind, ordinal)
-                out = prog(*args)
-            except RETRYABLE_FAULTS as e:
-                last = e
-                self._note_fault()
-                log.warning("%s launch %d attempt %d failed: %r",
-                            kind, ordinal, attempt, e)
-                continue
-            if self.watchdog.observe(time.time() - t0):
-                counters["stragglers"] += 1
-                self._note_fault()
-                log.warning("straggler %s launch %d", kind, ordinal)
-            return out
-        raise LaunchFailedError(kind, ordinal,
-                                self.fault_policy.max_retries + 1) from last
+        with self._spans.span("engine.launch") as span:
+            prog = self._program(name, args)
+            ordinal = self._launch_seq[kind]
+            self._launch_seq[kind] = ordinal + 1
+            counters = self.scheduler.counters
+            last: Optional[BaseException] = None
+            for attempt in range(self.fault_policy.max_retries + 1):
+                if attempt:
+                    counters["retries"] += 1
+                    time.sleep(self.fault_policy.backoff(attempt - 1,
+                                                         self._fault_rng))
+                # the watchdog sees a launch's wall time as a jit call would
+                # show it: a first launch includes its compile
+                t0 = span.start if attempt == 0 else time.perf_counter()
+                try:
+                    self.injector.before_launch(kind, ordinal)
+                    out = prog(*args)
+                except RETRYABLE_FAULTS as e:
+                    last = e
+                    self._note_fault()
+                    log.warning("%s launch %d attempt %d failed: %r",
+                                kind, ordinal, attempt, e)
+                    continue
+                if self.watchdog.observe(time.perf_counter() - t0):
+                    counters["stragglers"] += 1
+                    self._note_fault()
+                    log.warning("straggler %s launch %d", kind, ordinal)
+                return out
+            raise LaunchFailedError(kind, ordinal,
+                                    self.fault_policy.max_retries + 1) \
+                from last
 
     def _note_fault(self):
         """One fault event (failed attempt, straggler, poisoned row) lands
@@ -501,10 +513,11 @@ class Engine(Server):
         """Host-stage row ``i`` as its request's resume point (cadence:
         every ``snapshot_every`` generated tokens)."""
         s = self.slots[i]
-        self._snaps[s.req.uid] = {
-            "row": dcache.snapshot_row(self.cache, i),
-            "pos": s.pos, "n_out": len(s.req.out), "last": s.last_token,
-        }
+        with self._spans.span("engine.snapshot"):
+            self._snaps[s.req.uid] = {
+                "row": dcache.snapshot_row(self.cache, i),
+                "pos": s.pos, "n_out": len(s.req.out), "last": s.last_token,
+            }
         self.scheduler.counters["snapshots_taken"] += 1
 
     def _emit(self, i: int, tok: int) -> bool:
@@ -514,6 +527,8 @@ class Engine(Server):
         slot = self.slots[i]
         r = slot.req
         r.out.append(tok)
+        if r.first_token is None:
+            r.first_token = time.perf_counter()
         # slot.pos is the NEXT write position: at max_len the cache is full
         stop = (len(r.out) >= r.max_new
                 or (self.eos_id is not None and tok == self.eos_id)
@@ -537,6 +552,8 @@ class Engine(Server):
         # pop in descending queue order so earlier indices stay valid
         for slot_id, qidx in sorted(matched, key=lambda m: -m[1]):
             req = self.queue.pop(qidx)
+            if req.admitted is None:
+                req.admitted = time.perf_counter()
             snap = self._snaps.get(req.uid)
             if snap is not None:
                 # resume from the last row snapshot: restore the row slices
@@ -578,40 +595,47 @@ class Engine(Server):
                 if s.state == "prefill" and s.filled > 0]
         for group, name in ((first, "prefill_first"),
                             (cont, "prefill_cont")):
-            if not group:
-                continue
-            toks = np.zeros((self.max_batch, self.chunk), np.int32)
-            offset = np.zeros((self.max_batch,), np.int32)
-            lens = np.zeros((self.max_batch,), np.int32)
-            for i, s in enumerate(self.slots):
-                if i in group:
-                    end = min(s.filled + self.chunk, len(s.prompt))
-                    toks[i, :end - s.filled] = s.prompt[s.filled:end]
-                    offset[i] = s.filled
-                    lens[i] = end - s.filled
-                else:  # park: overwritten before anything attends it
-                    offset[i] = s.context
-            extras = None
-            if name == "prefill_first" and self._extras_host is not None:
-                extras = {k: jnp.asarray(v)
-                          for k, v in self._extras_host.items()}
-            nxt, self.cache = self._launch(
-                "prefill", name, self.params, jnp.asarray(toks),
-                jnp.asarray(offset), jnp.asarray(lens), self.cache, extras)
+            if group:
+                with self._spans.span("engine.prefill"):
+                    self._prefill_group(group, name)
+
+    def _prefill_group(self, group: list, name: str):
+        """One padded full-batch prefill launch for the slots in ``group``,
+        then each slot's progress (and first token, when its prompt is
+        done)."""
+        toks = np.zeros((self.max_batch, self.chunk), np.int32)
+        offset = np.zeros((self.max_batch,), np.int32)
+        lens = np.zeros((self.max_batch,), np.int32)
+        for i, s in enumerate(self.slots):
+            if i in group:
+                end = min(s.filled + self.chunk, len(s.prompt))
+                toks[i, :end - s.filled] = s.prompt[s.filled:end]
+                offset[i] = s.filled
+                lens[i] = end - s.filled
+            else:  # park: overwritten before anything attends it
+                offset[i] = s.context
+        extras = None
+        if name == "prefill_first" and self._extras_host is not None:
+            extras = {k: jnp.asarray(v)
+                      for k, v in self._extras_host.items()}
+        nxt, self.cache = self._launch(
+            "prefill", name, self.params, jnp.asarray(toks),
+            jnp.asarray(offset), jnp.asarray(lens), self.cache, extras)
+        with self._spans.span("engine.wait"):
             nxt = np.asarray(nxt)
-            self._n_chunks += 1
-            self._n_chunk_rows += len(group)
-            for i in group:
-                slot = self.slots[i]
-                slot.filled += int(lens[i])
-                slot.last_step = self._iter
-                if slot.filled >= len(slot.prompt):
-                    slot.state = "decode"
-                    slot.pos = len(slot.prompt)
-                    self.cache = dcache.set_row_valid(self.cache, i, True)
-                    tok = int(nxt[i])
-                    slot.last_token = tok
-                    self._emit(i, tok)
+        self._n_chunks += 1
+        self._n_chunk_rows += len(group)
+        for i in group:
+            slot = self.slots[i]
+            slot.filled += int(lens[i])
+            slot.last_step = self._iter
+            if slot.filled >= len(slot.prompt):
+                slot.state = "decode"
+                slot.pos = len(slot.prompt)
+                self.cache = dcache.set_row_valid(self.cache, i, True)
+                tok = int(nxt[i])
+                slot.last_token = tok
+                self._emit(i, tok)
 
     def _decode_step(self):
         """One batched per-row decode step over every decoding slot.  Rows
@@ -638,7 +662,8 @@ class Engine(Server):
         nxt, ok, self.cache = self._launch(
             "decode", "decode", self.params, jnp.asarray(toks),
             jnp.asarray(pos), self.cache, jnp.asarray(poison))
-        nxt, ok = np.asarray(nxt), np.asarray(ok)
+        with self._spans.span("engine.wait"):
+            nxt, ok = np.asarray(nxt), np.asarray(ok)
         self._n_decode_steps += 1
         for i in decoding:
             if not ok[i]:
@@ -710,8 +735,17 @@ class Engine(Server):
         self._iter = 0
         self._last_fault_iter = -(10 ** 9)
         self._active_limit = self.max_batch
-        self.busy_s = 0.0
-        self._t0 = time.time()
+        self._spans.reset()
+        self._t0 = time.perf_counter()
+        for r in self.queue:
+            if r.queued is None:
+                r.queued = self._t0
+
+    @property
+    def busy_s(self) -> float:
+        """Seconds spent in :meth:`step` since :meth:`begin`: the
+        ``engine.step`` span's total."""
+        return self._spans.total("engine.step")
 
     def busy(self) -> bool:
         """True while this replica still owes work: queued requests or any
@@ -721,20 +755,23 @@ class Engine(Server):
 
     def step(self):
         """One engine iteration: admit, batched prefill chunks, batched
-        per-row decode, pressure eviction, degradation bookkeeping.  Wall
-        time accrues to this replica's ``busy_s`` clock — in production
-        each replica is its own accelerator, so the fleet makespan is the
-        max of these clocks, which is how the router reports fleet
-        throughput when replicas time-share one test device."""
-        t0 = time.time()
-        with self.mesh, axis_rules(self.rules, self.mesh):
-            self._admit()
+        per-row decode, pressure eviction, degradation bookkeeping, inside
+        an ``engine.step`` span (its total is :attr:`busy_s`).  A profile
+        shows the phases as the spans ``engine.admit``, ``engine.prefill``
+        (one per prefill launch) and ``engine.decode``; inside the last
+        two, ``engine.launch`` (lookup, fault injection, dispatch, retries),
+        ``engine.wait`` (the host's blocking read of the launch's tokens)
+        and, in decode, ``engine.snapshot`` (a row's copy to the host)."""
+        with self._spans.span("engine.step"), self.mesh, \
+                axis_rules(self.rules, self.mesh):
+            with self._spans.span("engine.admit"):
+                self._admit()
             self._advance_prefill()
             if any(s.state == "decode" for s in self.slots):
-                self._decode_step()
+                with self._spans.span("engine.decode"):
+                    self._decode_step()
             self._apply_pressure()
             self._update_degradation()
-        self.busy_s += time.time() - t0
 
     def finish(self) -> dict:
         """Seal the run's counters (the injected-fault mirror lands in the
@@ -747,10 +784,13 @@ class Engine(Server):
     def stats(self) -> dict:
         """The engine's structured observability surface — scheduler
         counters, fault counters (``runtime.fault_tolerance`` keys), the
-        degradation-window state, slot occupancy, and the remaining-work
-        load signal.  The router's health scoring and load shedding read
-        THIS, never private attributes; live mid-run reads are supported
-        (the injected-fault mirror refreshes here)."""
+        degradation-window state, slot occupancy, the remaining-work load
+        signal, launches per kind, and the host spans of the run so far
+        (``spans``: per name, ``n`` closed, seconds ``s`` and self seconds
+        ``self_s``, outside the spans nested in it).  The router's health
+        scoring and load shedding read THIS, never private attributes; live
+        mid-run reads are supported (the injected-fault mirror refreshes
+        here)."""
         counters = self.scheduler.counters
         counters["faults_injected"] = (
             self.injector.counters["faults_injected"]
@@ -776,6 +816,7 @@ class Engine(Server):
             "work_remaining": self.work_remaining_total(),
             "launches": dict(self._launch_seq),
             "busy_s": self.busy_s,
+            "spans": self._spans.snapshot(),
         }
 
     # -- fleet-tier request movement -----------------------------------------
@@ -825,6 +866,8 @@ class Engine(Server):
         if snap is not None:
             dcache.snapshot_compatible(self.cache, snap["row"])
             self._snaps[req.uid] = snap
+        if req.queued is None:
+            req.queued = time.perf_counter()
         self.queue.append(req)
 
     def salvage(self) -> list[tuple[Request, Optional[dict]]]:
@@ -848,7 +891,7 @@ class Engine(Server):
         while self.busy():
             self.step()
         stats = self.finish()
-        dt = time.time() - self._t0
+        dt = time.perf_counter() - self._t0
         n_tokens = sum(len(r.out) for r in requests)
         return {
             "wall_s": dt,

@@ -76,12 +76,9 @@ error chained to its ``LaunchFailedError`` — the fleet never respawns
 forever.
 
 **Fleet clock.**  The router steps its replicas round-robin from one
-thread, and each engine accrues wall time on its own ``busy_s`` clock.
-When every replica is its own accelerator the rounds could overlap, so
-fleet throughput is reported against the MAKESPAN ``max(busy_s)``
-(``fleet_tok_per_s``) — the machine-checkable ratio the bench records —
-alongside the raw sequential wall (``tok_per_s``), which on a single
-shared device cannot show the fleet win and is kept for honesty.
+thread.  :meth:`Router.finish` reports the fleet's wall time and the
+tokens per second over it (``tok_per_s``), and each replica's time inside
+``Engine.step`` (``busy_s`` in its row).
 """
 from __future__ import annotations
 
@@ -240,21 +237,18 @@ class Router:
     def finish(self, requests: list[Request]) -> dict:
         """Seal every live engine's counters and assemble the fleet view:
         router counters, the placement log, per-replica provenance rows,
-        and both throughput clocks (see "Fleet clock" in the module
-        docstring)."""
+        and the fleet's wall time and throughput (see "Fleet clock" in the
+        module docstring)."""
         for rep in self.replicas:
             if rep.state == "live":
                 rep.engine.finish()
             rep.refresh_health()
         dt = time.time() - self._t0
-        fleet = max((r.engine.busy_s for r in self.replicas), default=dt)
         n_tokens = sum(len(r.out) for r in requests)
         return {
             "wall_s": dt,
-            "fleet_busy_s": fleet,
             "tokens": n_tokens,
             "tok_per_s": n_tokens / max(dt, 1e-9),
-            "fleet_tok_per_s": n_tokens / max(fleet, 1e-9),
             "counters": {k: (dict(v) if isinstance(v, dict) else v)
                          for k, v in self.counters.items()},
             "placements": list(self.placements),
@@ -509,9 +503,7 @@ def main():
     out = router.run(reqs)
     print(f"served {out['tokens']} tokens across "
           f"{len(out['replicas'])} replica row(s): "
-          f"fleet {out['fleet_tok_per_s']:.1f} tok/s (makespan "
-          f"{out['fleet_busy_s']:.2f}s), sequential {out['tok_per_s']:.1f} "
-          f"tok/s ({out['wall_s']:.2f}s)")
+          f"{out['tok_per_s']:.1f} tok/s ({out['wall_s']:.2f}s)")
     print(f"router counters: {out['counters']}")
     for row in out["replicas"]:
         print(f"replica {row['rid']}: state={row['state']} "

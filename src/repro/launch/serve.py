@@ -79,6 +79,12 @@ class Request:
     # modality-frontend inputs keyed by the model's batch_extras_specs()
     # (e.g. "image_embeds" / "audio_frames"), one row each, no batch axis
     extras: dict | None = None
+    # the serving engine's ``time.perf_counter`` stamps: queued (adopted, or
+    # queued by ``begin``), first admitted into a slot, first token emitted;
+    # a re-admission after an eviction or a move between replicas keeps them
+    queued: float | None = None
+    admitted: float | None = None
+    first_token: float | None = None
 
 
 class Server:

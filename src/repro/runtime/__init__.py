@@ -18,6 +18,7 @@ from repro.runtime.fault_tolerance import (
     parse_fleet_plan,
 )
 from repro.runtime.replica import Replica, health_score, spawn_replica
+from repro.runtime.spans import Spans
 
 __all__ = [
     "FAULT_COUNTER_KEYS",
@@ -27,6 +28,7 @@ __all__ = [
     "InjectedFault",
     "LaunchFailedError",
     "Replica",
+    "Spans",
     "StragglerMonitor",
     "elastic_restore",
     "export_fault_counters",

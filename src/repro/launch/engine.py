@@ -94,10 +94,17 @@ taxonomy, mapped onto launches):
     shrunken) mesh.  This engine owns tiers (a)–(c) only; it never
     catches its own escalation.
 
-Row snapshots (``models.cache.snapshot_row``/``restore_row``) are taken on
-a ``snapshot_every`` generated-token cadence, host-staged per request:
-recovery and ``cache_budget`` pressure eviction both resume from the last
-snapshot plus a short greedy replay instead of whole-residency recompute.
+Row snapshots (``models.cache.snapshot_row``'s format, restored by
+``restore_row``) are taken on a ``snapshot_every`` generated-token cadence,
+host-staged per request: recovery and ``cache_budget`` pressure eviction
+both resume from the last snapshot plus a short greedy replay instead of
+whole-residency recompute.  A snapshot moves only what changed
+(``models.cache.RowCopier``): a request's first copies its row's
+``LinearKV`` positions below the cursor, each later one (a cadence point,
+or the fresh one ``drain_slot`` stages) only the positions written since
+the previous, into that snapshot's host buffers; cursors, int8 scales and
+every other layout cross whole.  A request adopted with a snapshot takes
+the snapshot's cursor as the base of its next copy.
 A deterministic ``FaultInjector`` plan (``--inject`` / ``REPRO_FAULTS``,
 grammar ``decode@12=raise,prefill@3=delay:0.2,slot@2=nan_logits``) drives
 the same faults through tests, the CI smoke arm, and the bench recovery
@@ -169,6 +176,7 @@ class SlotScheduler:
             "faults_injected": 0,     # mirrored from the FaultInjector
             "slots_poisoned": 0,      # non-finite rows bisected + evicted
             "snapshots_taken": 0,     # host-staged row snapshots
+            "snapshot_bytes": 0,      # bytes those snapshots moved to host
             "snapshot_restores": 0,   # re-admissions resumed from a snapshot
             "stragglers": 0,          # watchdog-flagged slow launches
             "degradations": 0,        # active-slot-limit shrinks
@@ -265,6 +273,9 @@ class Engine(Server):
         self.injector = FaultInjector.from_env() if injector is None \
             else injector
         self.snapshot_every = int(snapshot_every)
+        # a first snapshot's prefix crosses in prefill-chunk blocks, a
+        # cadence tail in one block of the cadence
+        self._rows = dcache.RowCopier((self.chunk, self.snapshot_every))
         self.degrade_after = int(degrade_after)
         self.degrade_window = int(degrade_window)
         self.heal_after = int(heal_after)
@@ -415,11 +426,16 @@ class Engine(Server):
                 self.cache, extras)
 
     def compile(self) -> dict[str, float]:
-        """Compile all three launches ahead of serving (nothing runs).
+        """Compile all three launches ahead of serving (nothing runs), and
+        the row snapshot's copies for the cache as each launch leaves it.
         Returns the compile seconds per launch."""
         with self.mesh, axis_rules(self.rules, self.mesh):
             for name in self._jitted:
-                self._program(name, self._launch_args(name))
+                prog = self._program(name, self._launch_args(name))
+                self._rows.compile(jax.tree.map(
+                    lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                       sharding=sh),
+                    self.cache, prog.output_shardings[-1]))
         return dict(self.compile_s)
 
     # -- fault handling ------------------------------------------------------
@@ -511,14 +527,21 @@ class Engine(Server):
 
     def _take_snapshot(self, i: int):
         """Host-stage row ``i`` as its request's resume point (cadence:
-        every ``snapshot_every`` generated tokens)."""
+        every ``snapshot_every`` generated tokens).  Only the positions
+        written since the request's last snapshot cross, into its buffers;
+        the new entry replaces the old once the copy has landed."""
         s = self.slots[i]
+        prev = self._snaps.get(s.req.uid)
         with self._spans.span("engine.snapshot"):
+            row, moved = self._rows.copy(
+                self.cache, i, s.pos,
+                *((prev["row"], prev["pos"]) if prev else ()))
             self._snaps[s.req.uid] = {
-                "row": dcache.snapshot_row(self.cache, i),
-                "pos": s.pos, "n_out": len(s.req.out), "last": s.last_token,
+                "row": row, "pos": s.pos, "n_out": len(s.req.out),
+                "last": s.last_token,
             }
         self.scheduler.counters["snapshots_taken"] += 1
+        self.scheduler.counters["snapshot_bytes"] += moved
 
     def _emit(self, i: int, tok: int) -> bool:
         """Record one generated token for slot ``i``; returns True (and
@@ -862,7 +885,9 @@ class Engine(Server):
         is a host-staged resume entry whose row may have been captured on a
         DIFFERENT replica — row slices carry no slot or replica identity,
         but the layout must match, so it is validated against this
-        engine's cache before staging."""
+        engine's cache before staging.  The snapshot passes to this
+        engine: its later snapshots of the request are written into the
+        snapshot's host buffers, from its cursor on."""
         if snap is not None:
             dcache.snapshot_compatible(self.cache, snap["row"])
             self._snaps[req.uid] = snap

@@ -66,7 +66,8 @@ def _register(cls, data_fields, meta_fields=()):
 
 
 def _slice_axis(a, axis, i):
-    return jax.lax.slice_in_dim(a, i, i + 1, axis=axis)
+    # a dynamic slice: ``i`` may be traced, so one program serves every slot
+    return jax.lax.dynamic_slice_in_dim(a, i, 1, axis=axis)
 
 
 def _set_axis(a, axis, i, sub):
@@ -123,7 +124,8 @@ class LinearKV:
               else _slice_axis(self.v_scale, self.b_axis, i))
         return self.replace(k=_slice_axis(self.k, self.b_axis, i),
                             v=_slice_axis(self.v, self.b_axis, i),
-                            pos=self.pos[i:i + 1], k_scale=sc, v_scale=vc)
+                            pos=_slice_axis(self.pos, 0, i), k_scale=sc,
+                            v_scale=vc)
 
     def set_slot(self, i: int, sub: "LinearKV") -> "LinearKV":
         ks = (None if self.k_scale is None
@@ -142,6 +144,26 @@ class LinearKV:
 
     def lengths(self) -> jax.Array:
         return self.pos
+
+    def block_range(self, start: int, width: int) -> slice:
+        """The positions :meth:`block` reads for a block asked at
+        ``start``: ``width`` capped at the capacity, and a block that would
+        cross the capacity read at its end instead."""
+        width = min(width, self.capacity)
+        start = min(start, self.capacity - width)
+        return slice(start, start + width)
+
+    def block(self, i, start, width: int):
+        """Row ``i``'s k and v slabs at :meth:`block_range`, ``(*lead, 1,
+        width, kvh, hd)`` each; ``i`` and ``start`` may be traced (the
+        dynamic slice clamps the start as :meth:`block_range` does)."""
+        width = min(width, self.capacity)
+        idx = [0] * self.k.ndim
+        idx[self.b_axis], idx[self.b_axis + 1] = i, start
+        size = list(self.k.shape)
+        size[self.b_axis], size[self.b_axis + 1] = 1, width
+        return (jax.lax.dynamic_slice(self.k, idx, size),
+                jax.lax.dynamic_slice(self.v, idx, size))
 
 
 _register(LinearKV, ("k", "v", "pos", "k_scale", "v_scale"), ("b_axis",))
@@ -176,7 +198,7 @@ class RingKV:
     def slot(self, i: int) -> "RingKV":
         return self.replace(k=_slice_axis(self.k, self.b_axis, i),
                             v=_slice_axis(self.v, self.b_axis, i),
-                            pos=self.pos[i:i + 1])
+                            pos=_slice_axis(self.pos, 0, i))
 
     def set_slot(self, i: int, sub: "RingKV") -> "RingKV":
         return self.replace(k=_set_axis(self.k, self.b_axis, i, sub.k),
@@ -273,7 +295,7 @@ class StateCarry:
     def slot(self, i: int) -> "StateCarry":
         return StateCarry(
             states={k: _slice_axis(a, 1, i) for k, a in self.states.items()},
-            valid=self.valid[i:i + 1])
+            valid=_slice_axis(self.valid, 0, i))
 
     def set_slot(self, i: int, sub: "StateCarry") -> "StateCarry":
         return StateCarry(
@@ -303,13 +325,17 @@ _LAYOUTS = (LinearKV, RingKV, CrossKV, StateCarry)
 # composite helpers: a cache is a layout, or a dict/tuple of caches
 # ---------------------------------------------------------------------------
 
-def _map_layouts(cache, fn):
+def _map_layouts(cache, fn, *others):
+    """``fn`` over every layout of ``cache``, given the entries of
+    ``others`` (trees of the composite's structure) at the same place."""
     if isinstance(cache, _LAYOUTS):
-        return fn(cache)
+        return fn(cache, *others)
     if isinstance(cache, dict):
-        return {k: _map_layouts(v, fn) for k, v in cache.items()}
+        return {k: _map_layouts(v, fn, *(o[k] for o in others))
+                for k, v in cache.items()}
     if isinstance(cache, (tuple, list)):
-        return type(cache)(_map_layouts(v, fn) for v in cache)
+        return type(cache)(_map_layouts(v, fn, *os)
+                           for v, *os in zip(cache, *others))
     raise TypeError(f"not a DecodeCache composite: {type(cache)!r}")
 
 
@@ -320,11 +346,7 @@ def slot(cache, i: int):
 
 def set_slot(cache, i: int, sub):
     """Write a b=1 sub-cache back into slot ``i`` of every layout."""
-    if isinstance(cache, _LAYOUTS):
-        return cache.set_slot(i, sub)
-    if isinstance(cache, dict):
-        return {k: set_slot(v, i, sub[k]) for k, v in cache.items()}
-    return type(cache)(set_slot(v, i, s) for v, s in zip(cache, sub))
+    return _map_layouts(cache, lambda lo, s: lo.set_slot(i, s), sub)
 
 
 def reset_row(cache, i: int):
@@ -347,12 +369,155 @@ def snapshot_row(cache, i: int):
     """Host-staged copy of slot ``i`` across every layout: the b=1 pytree
     slice of the whole composite with numpy leaves, so the snapshot costs
     no device memory and survives the engine's donated-buffer launches.
-    Taken on a token-count cadence by the serving engine, it is the resume
-    point for BOTH fault recovery (a poisoned row) and pressure eviction —
-    restore plus a short greedy token replay instead of whole-residency
-    recompute.  Restore with :func:`restore_row`, into the same or a
-    DIFFERENT slot (row slices carry no slot identity)."""
+    This copies the whole row, every ``LinearKV`` position up to the
+    capacity included.  The serving engine fills the same format through
+    :class:`RowCopier`, which moves only the positions written since the
+    row's previous snapshot.  Taken on a token-count cadence by the
+    engine, it is the resume point for BOTH fault recovery (a poisoned
+    row) and pressure eviction — restore plus a short greedy token replay
+    instead of whole-residency recompute.  Restore with
+    :func:`restore_row`, into the same or a DIFFERENT slot (row slices
+    carry no slot identity)."""
     return jax.device_get(slot(cache, i))
+
+
+def _row_head(cache, i):
+    """Row ``i`` of everything a :class:`RowCopier` copies whole: every
+    layout but the ``LinearKV`` slabs, and of those their cursor and
+    scales."""
+    return _map_layouts(
+        slot(cache, i),
+        lambda lo: lo.replace(k=None, v=None) if isinstance(lo, LinearKV)
+        else lo)
+
+
+def _row_block(cache, i, start, *, width: int):
+    """Positions ``[start, start + width)`` of row ``i`` of every
+    ``LinearKV`` slab (a ``(k, v)`` pair in its place; None elsewhere)."""
+    return _map_layouts(
+        cache, lambda lo: lo.block(i, start, width)
+        if isinstance(lo, LinearKV) else None)
+
+
+def _new_slabs(lo):
+    """Zeroed host k and v buffers for one row of a ``LinearKV`` (None for
+    any other layout)."""
+    if not isinstance(lo, LinearKV):
+        return None
+    shape = list(lo.k.shape)
+    shape[lo.b_axis] = 1
+    return np.zeros(shape, lo.k.dtype), np.zeros(shape, lo.v.dtype)
+
+
+class RowCopier:
+    """Host snapshots of cache rows that move only what changed.
+
+    A ``LinearKV`` row is append-only while it holds one request: writes
+    land at the row's cursor, and a parked row writes at or beyond its
+    own.  So the positions below a row's previous snapshot are on the host
+    already, unchanged, and :meth:`copy` moves ``[since, upto)`` alone, in
+    place into the previous snapshot's slab buffers; a row's first
+    snapshot moves ``[0, upto)``.  Every other layout (``RingKV`` wraps,
+    ``CrossKV``, ``StateCarry``) and each ``LinearKV``'s cursor and scales
+    cross whole.  The result has :func:`snapshot_row`'s format and
+    equals it on every position below the cursor.
+
+    The positions cross in blocks of a few fixed ``widths``: the widest
+    while it fits, then the narrowest to the end.  Each block, and the
+    whole-copied part, is one compiled program with the slot and the
+    start traced, per cache placement (the shardings of its leaves);
+    :meth:`compile` builds them ahead of use, and a placement never seen
+    compiles on first use."""
+
+    def __init__(self, widths):
+        self.widths = tuple(sorted({int(w) for w in widths if w > 0},
+                                   reverse=True))
+        if not self.widths:
+            raise ValueError(f"RowCopier needs a positive width: {widths}")
+        self._programs: dict = {}
+
+    @staticmethod
+    def _placement(cache) -> tuple:
+        return tuple(x.sharding for x in jax.tree.leaves(cache))
+
+    def _program(self, width, placement, cache):
+        """The compiled copy of one block of ``width`` (None: the
+        whole-copied part) for caches placed as ``placement``."""
+        key = (width, placement)
+        prog = self._programs.get(key)
+        if prog is None:
+            scalar = jax.ShapeDtypeStruct((), jnp.int32)
+            if width is None:
+                prog = jax.jit(_row_head).lower(cache, scalar).compile()
+            else:
+                prog = jax.jit(_row_block, static_argnames="width").lower(
+                    cache, scalar, scalar, width=width).compile()
+            self._programs[key] = prog
+        return prog
+
+    def compile(self, cache) -> None:
+        """Build every program for caches placed as ``cache`` is (its
+        leaves may be ``jax.ShapeDtypeStruct`` with a sharding)."""
+        placement = self._placement(cache)
+        for width in (None,) + self._widths(cache):
+            self._program(width, placement, cache)
+
+    def _widths(self, cache) -> tuple:
+        """The block widths ``cache`` needs: none without a ``LinearKV``."""
+        found = []
+        _map_layouts(cache, lambda lo: found.append(isinstance(lo, LinearKV)))
+        return self.widths if any(found) else ()
+
+    @staticmethod
+    def _blocks(widths: tuple, since: int, upto: int) -> list:
+        """``(width, start)`` of the blocks that cover ``[since, upto)``:
+        the widest of ``widths`` while it fits, then the narrowest."""
+        blocks, at = [], since
+        for w in widths:
+            while upto - at >= w:
+                blocks.append((w, at))
+                at += w
+        while widths and at < upto:
+            blocks.append((widths[-1], at))
+            at += widths[-1]
+        return blocks
+
+    def copy(self, cache, i: int, upto: int, base=None, since: int = 0):
+        """Row ``i`` as a :func:`snapshot_row` at cursor ``upto``.  With
+        ``base``, the row's snapshot at cursor ``since``, only ``LinearKV``
+        positions ``[since, upto)`` cross, written into ``base``'s slab
+        buffers (``base`` stays a valid snapshot at ``since`` throughout:
+        nothing below ``since`` changes); without, ``[0, upto)`` cross
+        into new buffers.  Every program is dispatched before one read of
+        them all.  Returns the row and the bytes moved to the host."""
+        placement = self._placement(cache)
+        slot_i = np.int32(i)
+        blocks = self._blocks(self._widths(cache),
+                              since if base is not None else 0, upto)
+        outs = [self._program(None, placement, cache)(cache, slot_i)]
+        outs += [self._program(w, placement, cache)(cache, slot_i,
+                                                     np.int32(start))
+                 for w, start in blocks]
+        host = jax.device_get(outs)
+        moved = sum(x.nbytes for x in jax.tree.leaves(host))
+
+        slabs = (_map_layouts(cache, _new_slabs) if base is None else
+                 _map_layouts(cache, lambda lo, prev: (prev.k, prev.v)
+                              if isinstance(lo, LinearKV) else None, base))
+
+        def write(lo, bufs, *got):
+            if isinstance(lo, LinearKV):
+                for (w, start), kv in zip(blocks, got):
+                    seq = (slice(None),) * (lo.b_axis + 1) + (
+                        lo.block_range(start, w),)
+                    for buf, blk in zip(bufs, kv):
+                        buf[seq] = blk
+
+        _map_layouts(cache, write, slabs, *host[1:])
+        row = _map_layouts(
+            cache, lambda lo, head, bufs: head.replace(k=bufs[0], v=bufs[1])
+            if isinstance(lo, LinearKV) else head, host[0], slabs)
+        return row, moved
 
 
 def restore_row(cache, i: int, snap):

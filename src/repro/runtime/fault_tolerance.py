@@ -43,7 +43,8 @@ FAULT_PLAN_ENV = "REPRO_FAULTS"
 # out of the scheduler's counter dict instead of hard-coding its layout.
 FAULT_COUNTER_KEYS = (
     "retries", "faults_injected", "slots_poisoned", "snapshots_taken",
-    "snapshot_restores", "stragglers", "degradations", "degraded_iters",
+    "snapshot_bytes", "snapshot_restores", "stragglers", "degradations",
+    "degraded_iters",
 )
 
 

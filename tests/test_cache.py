@@ -295,3 +295,150 @@ def test_family_modules_never_mutate_cache_slabs_directly():
             assert not hits, (
                 f"{mod.__name__} line(s) {hits}: {why} — route it through "
                 f"repro.models.cache")
+
+
+# -- incremental snapshots: RowCopier against the whole-row copy --------------
+
+def _layouts_of(tree):
+    """The layouts of a composite, in a fixed order."""
+    if isinstance(tree, dict):
+        return [lo for k in sorted(tree) for lo in _layouts_of(tree[k])]
+    return [tree]
+
+
+def _incremental_cache(kind, rng, b=3):
+    """A cache of one layout kind (or the all-layout composite) whose row
+    1 stands at cursor 4, every slab position filled with noise."""
+    def f(*s):
+        return jnp.asarray(rng.standard_normal(s).astype(np.float32))
+    pos = jnp.asarray([2, 4, 9], jnp.int32)
+    if kind == "dense":
+        return dcache.LinearKV(k=f(2, b, 16, 2, 4), v=f(2, b, 16, 2, 4),
+                               pos=pos, k_scale=None, v_scale=None, b_axis=1)
+    if kind == "int8":
+        def q(*s):
+            return jnp.asarray(rng.integers(-127, 128, s), jnp.int8)
+        return dcache.LinearKV(k=q(b, 16, 2, 4), v=q(b, 16, 2, 4), pos=pos,
+                               k_scale=f(b, 2), v_scale=f(b, 2), b_axis=0)
+    if kind == "ring":
+        return dcache.RingKV(k=f(b, 8, 1, 4), v=f(b, 8, 1, 4), pos=pos,
+                             b_axis=0)
+    if kind == "state":
+        return dcache.StateCarry(states={"h": f(2, b, 5),
+                                         "conv": f(1, b, 3, 4)},
+                                 valid=jnp.asarray([True, True, False]))
+    cache = _rand_composite(rng, b)
+    cache["attn"] = cache["attn"].replace(pos=pos)
+    return cache
+
+
+def _decode_everywhere(cache, rng):
+    """One decode step of every row: each positional layout writes fresh
+    noise at each row's own cursor (none past a LinearKV's capacity, a
+    RingKV wraps) and advances it; recurrent state and int8 scales are
+    redrawn, so a copy that skipped them would show."""
+    def step(lo):
+        if isinstance(lo, (dcache.LinearKV, dcache.RingKV)):
+            k, v = np.array(lo.k), np.array(lo.v)
+            cap = lo.capacity
+            pos = np.array(lo.pos)
+            for r, p in enumerate(pos):
+                if isinstance(lo, dcache.LinearKV) and p >= cap:
+                    continue
+                at = (slice(None),) * lo.b_axis + (r, p % cap)
+                k[at] = rng.standard_normal(k[at].shape).astype(k.dtype)
+                v[at] = rng.standard_normal(v[at].shape).astype(v.dtype)
+                pos[r] = p + 1
+            new = lo.replace(k=jnp.asarray(k), v=jnp.asarray(v),
+                             pos=jnp.asarray(pos))
+            if isinstance(lo, dcache.LinearKV) and lo.quantized:
+                new = new.replace(
+                    k_scale=jnp.asarray(rng.random(lo.k_scale.shape),
+                                        jnp.float32),
+                    v_scale=jnp.asarray(rng.random(lo.v_scale.shape),
+                                        jnp.float32))
+            return new
+        if isinstance(lo, dcache.StateCarry):
+            return lo.replace(states={
+                k: jnp.asarray(rng.standard_normal(a.shape), a.dtype)
+                for k, a in lo.states.items()})
+        return lo
+    if isinstance(cache, dict):
+        return {k: step(v) for k, v in cache.items()}
+    return step(cache)
+
+
+def _assert_row_live_equal(got, want, pos):
+    """Rows equal on everything a restore resumes from: LinearKV slabs
+    below ``pos``, and every other leaf exactly."""
+    for g, w in zip(_layouts_of(got), _layouts_of(want)):
+        assert type(g) is type(w)
+        if isinstance(g, dcache.LinearKV):
+            live = (slice(None),) * (g.b_axis + 1) + (slice(0, pos),)
+            for a, b in ((g.k, w.k), (g.v, w.v)):
+                np.testing.assert_array_equal(np.asarray(a)[live],
+                                              np.asarray(b)[live])
+            g, w = g.replace(k=None, v=None), w.replace(k=None, v=None)
+        _assert_tree_equal(g, w)
+
+
+@pytest.mark.parametrize("points", [[7, 10, 13, 16], [7, 10, 13, 15, 16]],
+                         ids=["cadence", "clamped-tail"])
+@pytest.mark.parametrize("kind",
+                         ["dense", "int8", "ring", "state", "composite"])
+def test_incremental_snapshot_equals_full_snapshot(kind, points):
+    """A row written across several snapshot points: at each, the
+    incremental copy (a first one from position 0, then only the positions
+    written since the previous, into its buffers) equals the whole-row
+    ``snapshot_row`` below the cursor, with cursors, scales and every
+    other layout exact, and restores into a different slot as the row.
+    A delta moves one block of the narrow width; in ``clamped-tail`` the
+    last delta, [15, 16), is read as the block [13, 16) at the capacity."""
+    rng = np.random.default_rng(11)
+    cache = _incremental_cache(kind, rng)
+    copier = dcache.RowCopier((5, 3))
+    row, pos, prev = 1, 4, None
+    for upto in points:
+        while pos < upto:
+            cache = _decode_everywhere(cache, rng)
+            pos += 1
+        full = dcache.snapshot_row(cache, row)
+        got, moved = copier.copy(cache, row, pos,
+                                 *((prev, since) if prev else ()))
+        assert all(isinstance(x, np.ndarray) for x in jax.tree.leaves(got))
+        _assert_row_live_equal(got, full, pos)
+        slabs = [lo for lo in _layouts_of(full)
+                 if isinstance(lo, dcache.LinearKV)]
+        head = sum(x.nbytes for lo in _layouts_of(full)
+                   for x in jax.tree.leaves(
+                       lo.replace(k=None, v=None)
+                       if isinstance(lo, dcache.LinearKV) else lo))
+        per_pos = sum((lo.k.nbytes + lo.v.nbytes) // lo.capacity
+                      for lo in slabs)
+        if prev is None:
+            assert moved == head + per_pos * 8   # blocks [0, 5) and [5, 8)
+        else:
+            assert moved == head + per_pos * 3   # one narrow block
+            for g, p in zip(_layouts_of(got), _layouts_of(prev)):
+                if isinstance(g, dcache.LinearKV):  # written in place
+                    assert g.k is p.k and g.v is p.v
+        moved_to = dcache.restore_row(
+            jax.tree.map(jnp.zeros_like, cache), 0, got)
+        _assert_row_live_equal(jax.device_get(dcache.slot(moved_to, 0)),
+                               full, pos)
+        prev, since = got, pos
+
+
+def test_row_copier_builds_one_program_per_width():
+    """The copies take the slot and the start as traced scalars: copying
+    every slot at many starts uses the programs :meth:`RowCopier.compile`
+    built, one per block width plus the whole-copied part, and no other."""
+    cache = _incremental_cache("composite", np.random.default_rng(12))
+    copier = dcache.RowCopier((5, 3))
+    copier.compile(cache)
+    built = dict(copier._programs)
+    assert len(built) == 3
+    for i in range(3):
+        for upto in (1, 4, 9, 16):
+            copier.copy(cache, i, upto)
+    assert copier._programs == built

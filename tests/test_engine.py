@@ -117,6 +117,43 @@ def test_engine_pressure_eviction_requeues_and_finishes(mesh):
     assert all(len(r.out) == r.max_new for r in reqs)
 
 
+def _assert_snapshot_chains(log):
+    """Each request's snapshots chain: its first copies from position 0,
+    every later one from the cursor of the one before it."""
+    last = {}
+    for c in log:
+        assert c["since"] == last.get(c["uid"])
+        last[c["uid"]] = c["upto"]
+
+
+def test_engine_pressure_eviction_resumes_from_incremental_snapshots(
+        mesh, snapshot_copies):
+    """Pressure eviction with a snapshot every 2 tokens: evicted requests
+    resume from snapshots built by deltas and go on taking deltas from the
+    restored cursor.  Tokens equal the lockstep baseline and the run that
+    copies every snapshot's whole row, with as many snapshots; each
+    cadence delta moves at most one block of the cadence."""
+    cfg = get_smoke_config("qwen3-1.7b")
+    runs = {}
+    for whole in (False, True):
+        engine = Engine(cfg, mesh, max_batch=3, max_len=64, chunk=8,
+                        cache_budget=40, snapshot_every=2, opts=RunOptions())
+        log = snapshot_copies(engine, whole=whole)
+        reqs = _requests(6, vocab=cfg.vocab_size, max_new=12)
+        runs[whole] = (engine, reqs, engine.run(reqs)["telemetry"], log)
+    engine, reqs, tel, log = runs[False]
+    _, whole_reqs, whole_tel, _ = runs[True]
+    assert tel["pressure_evictions"] >= 1 and tel["snapshot_restores"] >= 1
+    assert check_lockstep_parity(engine, reqs)
+    assert [r.out for r in reqs] == [r.out for r in whole_reqs]
+    assert tel["snapshots_taken"] == whole_tel["snapshots_taken"] == len(log)
+    assert tel["snapshot_bytes"] == sum(c["moved"] for c in log)
+    assert tel["snapshot_bytes"] < whole_tel["snapshot_bytes"]
+    _assert_snapshot_chains(log)
+    deltas = [c for c in log if c["since"] is not None]
+    assert deltas and all(c["moved"] <= log.cadence_bound for c in deltas)
+
+
 def test_engine_batched_prefill_shares_launches(mesh):
     """Batched chunked prefill: with more fresh admissions than one, a
     single padded chunk launch serves >= 2 prefilling slots (chunk-rows

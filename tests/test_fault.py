@@ -230,6 +230,43 @@ def test_engine_poisoned_slot_bisected_and_restored(served):
     assert check_lockstep_parity(engine, reqs)
 
 
+def test_engine_poisoned_row_resumes_from_incremental_snapshot(
+        mesh, snapshot_copies):
+    """A row poisoned after a first snapshot and two deltas resumes from
+    the snapshot the deltas built: its tokens are the lockstep baseline's
+    and the whole-row-copy run's, with as many snapshots, and its next
+    snapshot is a delta from the restored cursor."""
+    cfg = get_smoke_config("qwen3-1.7b")
+    rng = np.random.default_rng(3)
+    spec = [(rng.integers(3, cfg.vocab_size, 9).astype(np.int32), 12)
+            for _ in range(2)]
+    runs = {}
+    for whole in (False, True):
+        # slot 0 snapshots at 2, 4 and 6 tokens, then poisons at its 7th
+        # decode launch
+        engine = Engine(cfg, mesh, max_batch=2, max_len=64, chunk=8,
+                        snapshot_every=2,
+                        injector=FaultInjector("slot@0=nan_logits:7"),
+                        opts=RunOptions())
+        log = snapshot_copies(engine, whole=whole)
+        reqs = [Request(i, p, max_new=mn) for i, (p, mn) in enumerate(spec)]
+        runs[whole] = (engine, reqs, engine.run(reqs)["telemetry"], log)
+    engine, reqs, tel, log = runs[False]
+    _, whole_reqs, whole_tel, _ = runs[True]
+    assert tel["slots_poisoned"] == 1 and tel["snapshot_restores"] == 1
+    assert check_lockstep_parity(engine, reqs)
+    assert [r.out for r in reqs] == [r.out for r in whole_reqs]
+    assert tel["snapshots_taken"] == whole_tel["snapshots_taken"] == len(log)
+    assert tel["snapshot_bytes"] < whole_tel["snapshot_bytes"]
+    row0 = [(c["since"], c["upto"]) for c in log if c["uid"] == 0]
+    prompt = len(spec[0][0])
+    # 2, 4, 6 tokens out; poisoned before the 8th; resumed at 6 tokens
+    assert row0[:4] == [(None, prompt + 1), (prompt + 1, prompt + 3),
+                        (prompt + 3, prompt + 5), (prompt + 5, prompt + 7)]
+    deltas = [c for c in log if c["since"] is not None]
+    assert all(c["moved"] <= log.cadence_bound for c in deltas)
+
+
 def test_engine_degradation_shrinks_and_heals(mesh):
     """Failure model (c): repeated faults inside the window shrink the
     active-slot limit; sustained health probes it back up — and the

@@ -217,6 +217,51 @@ def test_router_rebalance_migrates_decode_slot_exactly(mesh, cfg):
     _assert_single_replica_parity(router, spec, reqs)
 
 
+def test_drain_migration_takes_further_deltas_on_recipient(
+        mesh, cfg, snapshot_copies):
+    """A decoding row drained from one replica after a first snapshot and
+    a delta (``drain_slot`` stages one more delta) resumes on another
+    replica, whose snapshots of it are deltas from the adopted cursor.
+    Tokens equal a clean single-replica run and the run that copies every
+    snapshot's whole row, with as many snapshots on each replica."""
+    spec = [(np.arange(3, 14, dtype=np.int32), 14)]
+    runs = {}
+    for whole in (False, True):
+        donor, rec = (Engine(cfg, mesh, injector=FaultInjector(""), **_kw())
+                      for _ in range(2))
+        rec.params = donor.params
+        logs = [snapshot_copies(e, whole=whole) for e in (donor, rec)]
+        req, = _reqs(spec)
+        donor.begin([req])
+        while len(req.out) < 5:   # snapshots at 2 and 4 tokens
+            donor.step()
+        req, snap = donor.drain_slot(0)
+        rec.begin([])
+        rec.adopt(req, snap)
+        while rec.busy():
+            rec.step()
+        runs[whole] = (req, [e.finish()["faults"] for e in (donor, rec)],
+                       logs, snap["pos"])
+    single = Engine(cfg, mesh, injector=FaultInjector(""), **_kw())
+    single.params = donor.params
+    alone, = _reqs(spec)
+    single.run([alone])
+    req, faults, (dlog, rlog), adopted = runs[False]
+    whole_req, whole_faults, _, _ = runs[True]
+    assert req.out == whole_req.out == alone.out
+    assert len(req.out) == spec[0][1]
+    assert [f["snapshots_taken"] for f in faults] \
+        == [f["snapshots_taken"] for f in whole_faults] \
+        == [len(dlog), len(rlog)]
+    prompt = len(spec[0][0])
+    assert [(c["since"], c["upto"]) for c in dlog] == [
+        (None, prompt + 1), (prompt + 1, prompt + 3), (prompt + 3, adopted)]
+    assert rlog and rlog[0]["since"] == adopted
+    assert all(b["since"] == a["upto"] for a, b in zip(rlog, rlog[1:]))
+    assert all(c["moved"] <= rlog.cadence_bound for c in rlog)
+    assert faults[1]["snapshot_restores"] == 1
+
+
 # -- elastic join / leave -----------------------------------------------------
 
 def test_router_elastic_join_and_leave_token_identical(mesh, cfg):

@@ -23,6 +23,7 @@ def read(run):
     if not calls or not steps:
         return None
     least = sum(flops.least_time_s(*flops.attn_decode_call(
-        run.cfg, st["decode_kv"]), run.peak) for st in steps) / len(steps)
+        run.ref, run.cfg, st["decode_kv"]), run.peak)
+        for st in steps) / len(steps)
     mean_ns = sum(ev.dur_ns for ev in calls) / len(calls)
     return 100.0 * least / (mean_ns / 1e9)

@@ -10,7 +10,7 @@ DECODE_PROGRAM = "decode_rows"
 def read(run):
     busy_ns = sum(e.dur_ns for e in devtrace.module_events(run.trace,
                                                            DECODE_PROGRAM))
-    total = sum(flops.decode_flops(run.cfg, kv)
+    total = sum(flops.decode_flops(run.ref, run.cfg, kv)
                 for st in run.served.steps for kv in st["decode_kv"])
     if not busy_ns or not total:
         return None

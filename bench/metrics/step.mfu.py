@@ -4,12 +4,13 @@ import flops
 
 
 def read(run):
-    s, cfg = run.served, run.cfg
+    ref, cfg = run.ref, run.cfg
     total = 0
-    for st in s.steps:
+    for st in run.served.steps:
         for a, b in st["prefill_first"] + st["prefill_cont"]:
-            total += flops.prefill_flops(cfg, a, b)
-        total += sum(flops.decode_flops(cfg, kv) for kv in st["decode_kv"])
+            total += flops.prefill_flops(ref, cfg, a, b)
+        total += sum(flops.decode_flops(ref, cfg, kv)
+                     for kv in st["decode_kv"])
     if not total:
         return None
     return 100.0 * total / (run.peak["bf16_flops_per_s"] * run.trace.window_s)

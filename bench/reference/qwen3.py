@@ -16,6 +16,11 @@ Weights come in as arguments, with the leaves and shapes of
 ``lm_head`` (V, d); ``layer(i)`` returns layer ``i``'s leaves, matrices laid
 out (in, out).  Norm weights (``NORMS``) are the published scales.
 
+Beside the reference, the module ties the family to the harness: the
+program's ``ModelConfig`` (:func:`model_config`), the weights' layout, and
+the counts the operation metrics read (:func:`layer_matmul_params`,
+:func:`layer_attention`, :func:`head_params`), all from the published keys.
+
 ``quant`` selects the control: the same model with every projection, MLP
 and head matmul taking its operands rounded to a lower precision
 (``"int8"``: symmetric int8, per row of activations and per output column
@@ -32,9 +37,29 @@ import jax.numpy as jnp
 NORMS = ("ln1", "ln2", "q_norm", "k_norm", "final_norm")
 
 
+def model_config(cfg: dict):
+    """The program's ``ModelConfig`` for a config file, every field from
+    its published keys: the program's dense decoder with q/k norms."""
+    from repro.configs import ModelConfig
+
+    if cfg["use_sliding_window"]:
+        raise ValueError(f"{cfg['name']}: the Qwen3 reference attends every "
+                         "position; a sliding window is not modelled")
+    return ModelConfig(
+        name=cfg["name"], family="dense", n_layers=cfg["num_hidden_layers"],
+        d_model=cfg["hidden_size"], n_heads=cfg["num_attention_heads"],
+        n_kv_heads=cfg["num_key_value_heads"], d_ff=cfg["intermediate_size"],
+        vocab_size=cfg["vocab_size"], head_dim=cfg["head_dim"],
+        qkv_bias=cfg["attention_bias"], qk_norm=True,
+        rope_theta=float(cfg["rope_theta"]), norm_eps=cfg["rms_norm_eps"],
+        tie_embeddings=cfg["tie_word_embeddings"], dtype=cfg["torch_dtype"])
+
+
 def layout(cfg: dict) -> tuple[dict, dict]:
-    """(whole-model leaves, per-layer leaves), name -> shape, from the
-    published config keys: the weights this family's model holds."""
+    """(whole-model leaves, stacks of layers) from the published config
+    keys: the weights this family's model holds.  Leaves map name ->
+    shape, in the served dtype; the one stack, ``layers``, holds every
+    layer in order."""
     d, ff, v = cfg["hidden_size"], cfg["intermediate_size"], cfg["vocab_size"]
     h, kvh, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
                   cfg["head_dim"])
@@ -45,7 +70,30 @@ def layout(cfg: dict) -> tuple[dict, dict]:
              "wv": (d, kvh * hd), "wo": (h * hd, d), "q_norm": (hd,),
              "k_norm": (hd,), "w_gate": (d, ff), "w_up": (d, ff),
              "w_down": (ff, d)}
-    return top, layer
+    return top, {"layers": (range(cfg["num_hidden_layers"]), layer)}
+
+
+def layer_matmul_params(cfg: dict) -> list[int]:
+    """Per layer, the weights one token multiplies through: q, k, v, o and
+    the three MLP projections."""
+    d, ff = cfg["hidden_size"], cfg["intermediate_size"]
+    q = cfg["num_attention_heads"] * cfg["head_dim"]
+    kv = cfg["num_key_value_heads"] * cfg["head_dim"]
+    return [d * (q + 2 * kv) + q * d + 3 * d * ff] * cfg["num_hidden_layers"]
+
+
+def layer_attention(cfg: dict) -> list[dict]:
+    """Per layer, its attention: every layer attends every earlier
+    position."""
+    a = {"heads": cfg["num_attention_heads"],
+         "kv_heads": cfg["num_key_value_heads"], "head_dim": cfg["head_dim"],
+         "window": None}
+    return [a] * cfg["num_hidden_layers"]
+
+
+def head_params(cfg: dict) -> int:
+    """Weights a row whose logits are read multiplies through in the head."""
+    return cfg["hidden_size"] * cfg["vocab_size"]
 
 
 Q_BLOCK = 512   # query rows per attention block (bounds the score tensor)

@@ -122,6 +122,7 @@ class Run:
     """What a metric reader sees of one run."""
     cell: dict
     cfg: dict
+    ref: object           # the family's module, reference/<model_type>.py
     peak: dict
     open_loop: bool
     seconds: float
@@ -148,10 +149,10 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     entry = next(w for w in spec["workloads"] if w["name"] == name)
     cell = specs.workload(name, root)
     cfg, mix = cell["cfg"], cell["mix"]
+    reference = specs.reference(cfg["model_type"], root)
     devs = require_accelerator(entry["chips"])
     dev = devs[0]
     peak = specs.peaks(dev.device_kind, root)
-    reference = specs.reference(cfg["model_type"], root)
     readers = {m["name"]: (m, specs.metric_reader(m["name"], root))
                for m in specs.metrics_for(spec, name, trace)}
 
@@ -179,8 +180,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
         import devtrace
         reduced = devtrace.load(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
-    run = Run(cell, cfg, peak, open_loop, seconds, setup_s, served, tracked,
-              reduced)
+    run = Run(cell, cfg, reference, peak, open_loop, seconds, setup_s, served,
+              tracked, reduced)
     metrics = {}
     for mname, (m, read) in readers.items():
         value = read(run)

@@ -9,36 +9,18 @@ latency; how late adoption ran is recorded beside it.
 """
 from __future__ import annotations
 
-import dataclasses
 import time
 from dataclasses import dataclass, field
 
 import numpy as np
 
 
-def model_config(cfg: dict):
-    """The program's ``ModelConfig`` for a config file: the registered
-    architecture, with every size taken from the published keys."""
-    from repro.configs import get_config
-
-    base = get_config(cfg["arch"])
-    if base.family != "dense":
-        raise ValueError(f"{cfg['arch']}: the harness serves dense decoders")
-    return dataclasses.replace(
-        base, name=cfg["name"], n_layers=cfg["num_hidden_layers"],
-        d_model=cfg["hidden_size"], n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], d_ff=cfg["intermediate_size"],
-        vocab_size=cfg["vocab_size"], head_dim=cfg["head_dim"],
-        rope_theta=float(cfg["rope_theta"]), norm_eps=cfg["rms_norm_eps"],
-        tie_embeddings=cfg["tie_word_embeddings"],
-        qkv_bias=cfg["attention_bias"], dtype=cfg["torch_dtype"])
-
-
 def build_engine(cfg: dict, seed: int, ref):
-    """The cell's engine on a one-device mesh, with the config's ``engine``
-    settings (``Engine``'s own keyword arguments; every other setting at
-    its default), no fault plan, and the weights of ``seed``, laid out as
-    the family's reference module ``ref`` says, in place of its own."""
+    """The cell's engine on a one-device mesh: the ``ModelConfig`` that the
+    family's reference module ``ref`` makes of the config file, the
+    config's ``engine`` settings (``Engine``'s own keyword arguments; every
+    other setting at its default), no fault plan, and the weights of
+    ``seed``, laid out as ``ref`` says, in place of its own."""
     import jax
 
     from repro.launch.engine import Engine
@@ -49,7 +31,7 @@ def build_engine(cfg: dict, seed: int, ref):
     import weights
 
     mesh = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
-    engine = Engine(model_config(cfg), mesh, injector=FaultInjector(""),
+    engine = Engine(ref.model_config(cfg), mesh, injector=FaultInjector(""),
                     opts=RunOptions(), **cfg["engine"])
     shapes = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
                           engine.params)

@@ -10,10 +10,20 @@ sits in a file of its own under this directory, named after it:
     workloads/<cell>.json     the cell: its config, its traffic, its rate,
                               warm-up, drain and correctness limits
     metrics/<metric>.py       a reader: ``read(run) -> float | None``
-    reference/<model_type>.py the plain float32 reference of a model family
+    reference/<model_type>.py a model family's whole tie to the harness
 
 A new one is a new file plus its entry in ``BENCHMARK.json``; no file that
-is already here needs an edit.
+is already here needs an edit.  The harness names no family: a config's
+``model_type`` finds its module, which gives, each from the config file's
+keys (with ``reduced`` and ``assumed`` where a cut needs them):
+
+    model_config(cfg)         the program's ``ModelConfig``, every field
+    layout(cfg), NORMS        the weights' leaves, stacks and dtypes, and
+                              which leaves are norms (see ``weights.py``)
+    layer_matmul_params(cfg), layer_attention(cfg), head_params(cfg)
+                              what the operation counts read (``flops.py``)
+    logits(tokens, rows, cfg, top, layer, quant=None)
+                              the plain float32 reference (``check.py``)
 """
 from __future__ import annotations
 
@@ -70,8 +80,8 @@ def _named(kind: str, name: str, root: Path):
 
 
 def reference(model_type: str, root: Path = BENCH):
-    """``reference/<model_type>.py``: the family's plain reference and the
-    ``layout`` of its weights."""
+    """``reference/<model_type>.py``: the module of the config's family
+    (a missing one raises, naming the file)."""
     return _named("reference", model_type, root)
 
 

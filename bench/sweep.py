@@ -39,8 +39,8 @@ def main(argv=None) -> int:
     cfg, mix = cell["cfg"], cell["mix"]
     run.require_accelerator(1)
     run._watch_programs()
-    engine = serving.build_engine(cfg, args.seed,
-                                  spec.reference(cfg["model_type"]))
+    ref = spec.reference(cfg["model_type"])
+    engine = serving.build_engine(cfg, args.seed, ref)
     engine.compile()
     serving.warm_slots(engine)
     for rate in args.rates:
@@ -54,8 +54,8 @@ def main(argv=None) -> int:
             on_window_end=lambda: queued.update(
                 w1=engine.stats()["occupancy"]["queued"]),
             log_steps=True)
-        view = run.Run(cell, cfg, None, True, args.seconds, 0.0, served,
-                       served.tracked(True))
+        view = run.Run(cell, cfg, ref, None, True, args.seconds, 0.0,
+                       served, served.tracked(True))
         due = view.tracked
         print(json.dumps({
             "rate_per_s": rate, "queued_w0": queued["w0"],

@@ -9,32 +9,46 @@ import serving
 import spec
 
 CFG = spec.config("qwen3-1.7b")
+REF = spec.reference(CFG["model_type"])
 PEAK = spec.peaks("TPU v5 lite")
+# Qwen3-1.7B's attention: 16 query heads of 128 in each of 28 layers
+HEADS, HEAD_DIM, LAYERS = 16, 128, 28
 
 
 def test_layer_params_match_the_published_model():
     # Qwen3-1.7B: 2.03 B parameters with the embedding, 1.72 B without
     # the tied head counted twice; 28 layers of attention and MLP
-    per_layer = flops.layer_matmul_params(CFG)
-    assert per_layer == 2048 * (2048 + 2 * 1024) + 2048 * 2048 + 3 * 2048 * 6144
-    assert 28 * per_layer + 151936 * 2048 == pytest.approx(1.72e9, rel=0.01)
+    per_layer = 2048 * (2048 + 2 * 1024) + 2048 * 2048 + 3 * 2048 * 6144
+    assert REF.layer_matmul_params(CFG) == [per_layer] * LAYERS
+    assert (sum(REF.layer_matmul_params(CFG)) + REF.head_params(CFG)
+            == pytest.approx(1.72e9, rel=0.01))
 
 
 def test_attention_flops_sum_every_causal_row():
-    m = flops.dims(CFG)
-    brute = sum(4 * m["h"] * m["hd"] * (p + 1) * m["layers"]
+    brute = sum(4 * HEADS * HEAD_DIM * (p + 1) * LAYERS
                 for p in range(100, 356))
-    assert flops.attention_flops(CFG, 100, 356) == brute
+    assert flops.attention_flops(REF, CFG, 100, 356) == brute
+
+
+def test_windowed_attention_flops_count_the_window_only():
+    layers = [{"heads": 2, "kv_heads": 1, "head_dim": 8, "window": 5},
+              None, {"heads": 2, "kv_heads": 1, "head_dim": 8,
+                     "window": None}]
+    ref = SimpleNamespace(layer_attention=lambda cfg: layers)
+    brute = sum(4 * 2 * 8 * (min(p + 1, 5) + p + 1) for p in range(3, 12))
+    assert flops.attention_flops(ref, {}, 3, 12) == brute
 
 
 def test_chunked_prefill_adds_up_to_whole_prefill():
-    whole = flops.prefill_flops(CFG, 0, 768, logits_rows=3)
-    parts = sum(flops.prefill_flops(CFG, a, a + 256) for a in (0, 256, 512))
+    whole = flops.prefill_flops(REF, CFG, 0, 768, logits_rows=3)
+    parts = sum(flops.prefill_flops(REF, CFG, a, a + 256)
+                for a in (0, 256, 512))
     assert parts == whole
 
 
 def test_decode_token_is_a_one_row_prefill():
-    assert flops.decode_flops(CFG, 1000) == flops.prefill_flops(CFG, 999, 1000)
+    assert (flops.decode_flops(REF, CFG, 1000)
+            == flops.prefill_flops(REF, CFG, 999, 1000))
 
 
 def test_matmul_and_roofline_bounds():
@@ -49,10 +63,10 @@ def test_matmul_and_roofline_bounds():
 
 
 def test_attn_decode_call_reads_each_live_row_once():
-    f, b = flops.attn_decode_call(CFG, [10, 30])
-    m = flops.dims(CFG)
-    assert f == 4 * m["h"] * m["hd"] * 40
-    assert b == 2 * 40 * m["kvh"] * m["hd"] * 2 + 2 * 2 * m["q"] * 2
+    f, b = flops.attn_decode_call(REF, CFG, [10, 30])
+    kv_heads = 8
+    assert f == 4 * HEADS * HEAD_DIM * 40
+    assert b == 2 * 40 * kv_heads * HEAD_DIM * 2 + 2 * 2 * HEADS * HEAD_DIM * 2
 
 
 def _rec(uid, due, times, plen=100, max_new=None):

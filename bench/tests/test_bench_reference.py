@@ -6,7 +6,6 @@ import numpy as np
 import pytest
 
 import benchroot
-import serving
 import spec
 import weights
 
@@ -18,7 +17,7 @@ def _program(cfg, seed):
     from repro.models import build_model
     from repro.models.base import RunOptions
 
-    mc = serving.model_config(cfg)
+    mc = REF.model_config(cfg)
     opts = planner.resolve_run_options(RunOptions(), head_dim=mc.head_dim_,
                                        dtype=mc.activation_dtype)
     model = build_model(mc, opts)

@@ -7,13 +7,21 @@ to the served dtype.  Only integer operations and one correctly rounded
 multiply are involved, so a layer generated alone (for the reference) is
 bit for bit the slice of the stacked tree generated for the program.
 
-Which leaves there are, and their shapes, is the model family's own: its
-reference module (``reference/<model_type>.py``) gives ``layout(cfg)`` and
-the names of its norm leaves, ``NORMS``.  Other leaves are uniform with
-the standard deviation of the config's ``initializer_range``.  Norm leaves
-hold an offset: the program's RMSNorm scales by ``1 + w`` and stores
-``w``; the published model scales by ``g``, so the reference receives
-``g = 1 + w``.
+Which leaves there are, their shapes and dtypes, is the model family's
+own: its reference module (``reference/<model_type>.py``) gives
+``layout(cfg)`` and the names of its norm leaves, ``NORMS``.  The layout
+is ``(top, stacks)``: ``top`` maps a whole-model leaf's name to its shape;
+``stacks`` maps the program's path of a stack of layers (``"layers"``,
+``"groups/attn"``) to ``(layers, leaves)``, the model's indices of the
+layers it holds, in order, and each leaf's shape in one layer.  A shape is
+in the served dtype (``torch_dtype``); a ``(shape, dtype)`` pair gives a
+leaf its own.  A stacked leaf is a function of its name and its layer's
+model index, whichever stack holds it.
+
+Leaves are uniform with the standard deviation of the config's
+``initializer_range``, norm leaves with ``NORM_STD``.  Norm leaves hold an
+offset: the program's RMSNorm scales by ``1 + w`` and stores ``w``; the
+published model scales by ``g``, so the reference receives ``g = 1 + w``.
 """
 from __future__ import annotations
 
@@ -45,19 +53,45 @@ def _std(ref, cfg: dict, name: str) -> float:
     return NORM_STD if name in ref.NORMS else float(cfg["initializer_range"])
 
 
+def _shape_dtype(spec, served) -> tuple:
+    """A layout entry as (shape, dtype): a shape is in the served dtype."""
+    if len(spec) == 2 and isinstance(spec[1], str):
+        return tuple(spec[0]), jnp.dtype(spec[1])
+    return tuple(spec), served
+
+
+def _layout(ref, cfg: dict) -> tuple[dict, dict]:
+    """The family's layout with every leaf as (shape, dtype) and every
+    stack's layers as a tuple."""
+    top, stacks = ref.layout(cfg)
+    served = jnp.dtype(cfg["torch_dtype"])
+    top = {k: _shape_dtype(s, served) for k, s in top.items()}
+    stacks = {path: (tuple(layers), {k: _shape_dtype(s, served)
+                                     for k, s in leaves.items()})
+              for path, (layers, leaves) in stacks.items()}
+    return top, stacks
+
+
+def _nest(tree: dict, path: str, value) -> None:
+    *outer, last = path.split("/")
+    for k in outer:
+        tree = tree.setdefault(k, {})
+    tree[last] = value
+
+
 def program_params(ref, cfg: dict, seed: int, program_shapes,
                    shardings=None):
-    """The program's parameter tree (``{"embed", "final_norm", ["lm_head"],
-    "layers": {name: (L, ...)}}``) made in one jitted call on the device.
-    ``program_shapes`` is the program's own abstract tree; it must hold
-    exactly the leaves of the reference module ``ref``'s ``layout``, or the
-    weights could not mean the same model to both sides."""
-    top, layer = ref.layout(cfg)
-    n_layers = cfg["num_hidden_layers"]
-    dtype = jnp.dtype(cfg["torch_dtype"])
-    want = {**{k: (s, dtype) for k, s in top.items()},
-            **{f"layers/{k}": ((n_layers, *s), dtype)
-               for k, s in layer.items()}}
+    """The program's parameter tree (whole-model leaves and each stack of
+    layers at its path, every stacked leaf ``(layers, ...)``) made in one
+    jitted call on the device.  ``program_shapes`` is the program's own
+    abstract tree; it must hold exactly the leaves of the reference module
+    ``ref``'s ``layout``, or the weights could not mean the same model to
+    both sides."""
+    top, stacks = _layout(ref, cfg)
+    want = {**top,
+            **{f"{path}/{k}": ((len(layers), *s), dt)
+               for path, (layers, leaves) in stacks.items()
+               for k, (s, dt) in leaves.items()}}
     flat = jax.tree_util.tree_flatten_with_path(program_shapes)[0]
     have = {"/".join(str(getattr(p, "key", p)) for p in path):
             (tuple(a.shape), jnp.dtype(a.dtype)) for path, a in flat}
@@ -66,13 +100,14 @@ def program_params(ref, cfg: dict, seed: int, program_shapes,
                          f"are not the layout {sorted(want.items())}")
 
     def make(key):
-        out = {k: leaf(key, k, 0, s, dtype, _std(ref, cfg, k))
-               for k, s in top.items()}
-        out["layers"] = {
-            k: jax.vmap(lambda i, k=k, s=s: leaf(key, k, i, s, dtype,
-                                                 _std(ref, cfg, k)))(
-                jnp.arange(n_layers))
-            for k, s in layer.items()}
+        out = {k: leaf(key, k, 0, s, dt, _std(ref, cfg, k))
+               for k, (s, dt) in top.items()}
+        for path, (layers, leaves) in stacks.items():
+            _nest(out, path, {
+                k: jax.vmap(lambda i, k=k, s=s, dt=dt: leaf(
+                    key, k, i, s, dt, _std(ref, cfg, k)))(
+                    jnp.asarray(layers, jnp.int32))
+                for k, (s, dt) in leaves.items()})
         return out
 
     return jax.jit(make, out_shardings=shardings)(seed_key(seed))
@@ -84,32 +119,38 @@ def _published(ref, name: str, w):
 
 
 def reference_top(ref, cfg: dict, seed: int) -> dict:
-    """Whole-model leaves for the reference, in the served dtype (the
+    """Whole-model leaves for the reference, each in its layout dtype (the
     reference widens them where it uses them); norms as published scales
     in float32."""
-    top, _ = ref.layout(cfg)
-    dtype = jnp.dtype(cfg["torch_dtype"])
+    top, _ = _layout(ref, cfg)
 
     def make(key):
-        out = {k: leaf(key, k, 0, s, dtype, _std(ref, cfg, k))
-               for k, s in top.items()}
+        out = {k: leaf(key, k, 0, s, dt, _std(ref, cfg, k))
+               for k, (s, dt) in top.items()}
         return {k: _published(ref, k, w) if k in ref.NORMS else w
                 for k, w in out.items()}
 
     return jax.jit(make)(seed_key(seed))
 
 
-def reference_layer_fn(ref, cfg: dict, seed: int):
-    """``fn(i)`` -> layer ``i``'s leaves in float32, norms as published
-    scales; compiled once for every layer."""
-    _, layer = ref.layout(cfg)
-    dtype = jnp.dtype(cfg["torch_dtype"])
-    key = seed_key(seed)
-
+def _layer_maker(ref, cfg: dict, leaves: dict):
     @jax.jit
     def make(key, i):
-        return {k: _published(ref, k, leaf(key, k, i, s, dtype,
+        return {k: _published(ref, k, leaf(key, k, i, s, dt,
                                            _std(ref, cfg, k)))
-                for k, s in layer.items()}
+                for k, (s, dt) in leaves.items()}
 
-    return lambda i: make(key, jnp.int32(i))
+    return make
+
+
+def reference_layer_fn(ref, cfg: dict, seed: int):
+    """``fn(i)`` -> the leaves of the model's layer ``i``, from whichever
+    stack holds it, in float32, norms as published scales; compiled once
+    for each stack."""
+    _, stacks = _layout(ref, cfg)
+    key = seed_key(seed)
+    maker = {}
+    for layers, leaves in stacks.values():
+        make = _layer_maker(ref, cfg, leaves)
+        maker.update(dict.fromkeys(layers, make))
+    return lambda i: maker[i](key, jnp.int32(i))
